@@ -2,17 +2,14 @@
 
 Run:  PYTHONPATH=src python examples/serve_lm.py
 """
-import sys
-
 from repro.launch import serve as serve_mod
 
 
 def main():
-    sys.argv = [
-        "serve", "--arch", "smollm-360m", "--reduced",
+    serve_mod.main([
+        "--arch", "smollm-360m", "--reduced",
         "--requests", "6", "--max-new", "12", "--max-batch", "3",
-    ]
-    serve_mod.main()
+    ])
 
 
 if __name__ == "__main__":

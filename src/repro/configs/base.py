@@ -277,6 +277,15 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
     return ModelConfig(**kw)
 
 
+def with_depth(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """The same architecture at its published widths, cut to ``n_layers``
+    layers (configs whose layers form one stage of one block kind)."""
+    if len(cfg.stages) != 1 or len(cfg.stages[0].kinds) != 1:
+        raise ValueError(f"{cfg.name}: with_depth needs a single one-kind stage")
+    stage = dataclasses.replace(cfg.stages[0], repeats=n_layers)
+    return dataclasses.replace(cfg, n_layers=n_layers, stages=(stage,))
+
+
 # ---------------------------------------------------------------------------
 # Input shapes assigned to every architecture (the 4-shape set)
 # ---------------------------------------------------------------------------

@@ -319,10 +319,12 @@ def noisy_crossbar_accumulate(
     G = Kp // spec.rows
     planes = _grouped_planes(x_codes, spec)
     slices = g_eff.astype(jnp.float32).reshape(g_eff.shape[0], G, spec.rows, g_eff.shape[2])
+    # HIGHEST: the cells' 10 significant bits must not be rounded to bf16
     raw = jnp.einsum(
         "tbgr,sgrn->tsbgn",
         planes.astype(jnp.float32),
         slices,
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
     # ADC sampling of the analog column current: round-half-up, saturating.

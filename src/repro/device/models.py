@@ -230,12 +230,14 @@ def effective_drift_nu(cfg: DeviceConfig) -> float:
     """
     if cfg.drift_ea_ev == 0.0 or cfg.temp_k == DRIFT_T_REF_K:
         return cfg.drift_nu
-    scale = float(
-        jnp.exp(
-            (cfg.drift_ea_ev / BOLTZMANN_EV_K)
-            * (1.0 / DRIFT_T_REF_K - 1.0 / cfg.temp_k)
+    # a host constant even while a programming pass is being traced
+    with jax.ensure_compile_time_eval():
+        scale = float(
+            jnp.exp(
+                (cfg.drift_ea_ev / BOLTZMANN_EV_K)
+                * (1.0 / DRIFT_T_REF_K - 1.0 / cfg.temp_k)
+            )
         )
-    )
     return cfg.drift_nu * scale
 
 
@@ -302,7 +304,7 @@ def age_effective_codes(
 
 
 def ir_drop_conductance(
-    g: jnp.ndarray, spec: CrossbarSpec, cfg: DeviceConfig, col_offset: int = 0
+    g: jnp.ndarray, spec: CrossbarSpec, cfg: DeviceConfig, col_offset=0
 ) -> jnp.ndarray:
     """First-order line-resistance attenuation (AG2048 model, closed form).
 
@@ -314,15 +316,15 @@ def ir_drop_conductance(
 
     ``g``: (S, K, N) conductances; K is the contraction dim (wordlines, row
     ``i = k mod rows`` within its group), N the bitlines.  ``col_offset``
-    shifts the wordline position of column 0 — ``device.repair`` reads each
-    spare block at the position just past its own column group's data
-    columns, not at the near-driver corner.
+    (a scalar, or one value per column) shifts the wordline positions —
+    ``device.repair`` reads each spare block at the position just past its
+    own column group's data columns, not at the near-driver corner.
     """
     if cfg.r_line_ohm == 0.0:
         return g
     S, K, N = g.shape
     i = (jnp.arange(K, dtype=jnp.int32) % spec.rows).astype(jnp.float32)
-    j = jnp.arange(N, dtype=jnp.float32) + float(col_offset)
+    j = jnp.arange(N, dtype=jnp.float32) + jnp.asarray(col_offset, jnp.float32)
     r_series = ((j[None, :] + 1.0) + (spec.rows - i[:, None])) * cfg.r_line_ohm
     return g / (1.0 + g * r_series[None, :, :])
 
@@ -401,7 +403,7 @@ def programmed_conductance(
 
 
 def read_effective_codes(
-    g: jnp.ndarray, spec: CrossbarSpec, cfg: DeviceConfig, col_offset: int = 0
+    g: jnp.ndarray, spec: CrossbarSpec, cfg: DeviceConfig, col_offset=0
 ) -> jnp.ndarray:
     """Read-time view of programmed conductances, in grid-quantized code units.
 

@@ -40,6 +40,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import threading
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -366,9 +367,12 @@ def program_layer(
         # (bit-identical cells, pinned by test_programming_is_deterministic)
         from repro.device import repair as repair_mod
 
-        g_eff, rplan, report = repair_mod.repaired_effective_cells(
-            wb, spec, device, with_report=with_report
-        )
+        if with_report:
+            g_eff, rplan, report = repair_mod.repaired_effective_cells(
+                wb, spec, device, with_report=True
+            )
+        else:
+            g_eff, rplan = _programmed_cells(wb, spec, device)
         if rplan is not None:
             g_spare = rplan.g_spare
             out_gather = rplan.out_gather
@@ -381,6 +385,21 @@ def program_layer(
         spec=spec, adc_cfg=adc_cfg, fast=fast, report=report, repair=repair_rep,
         device=device, t_service_s=0.0, plan=plan,
     )
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _programmed_cells(wb: jnp.ndarray, spec: CrossbarSpec, device: dm.DeviceConfig):
+    """(repaired g_eff, repair plan) of one biased (K, N) code slab, as one
+    compiled program.
+
+    Run op by op, the programming pipeline dispatches hundreds of small
+    operations, and an accelerator compiles each of them for every distinct
+    slab shape; jitted, each (shape, spec, device) compiles once.
+    """
+    from repro.device import repair as repair_mod
+
+    g_eff, rplan, _ = repair_mod.repaired_effective_cells(wb, spec, device)
+    return g_eff, rplan
 
 
 # ---------------------------------------------------------------------------

@@ -155,6 +155,14 @@ class RepairPlan:
     rows: int = 128
 
 
+# a pytree, so a jitted programming pass can return the plan
+jax.tree_util.register_dataclass(
+    RepairPlan,
+    data_fields=["victim", "out_gather", "g_spare", "salience_before", "salience_after"],
+    meta_fields=["rows"],
+)
+
+
 @dataclasses.dataclass(frozen=True)
 class RepairReport:
     """Host-side summary of a ``RepairPlan`` (hashable: rides pytree aux).
@@ -237,9 +245,10 @@ def plan_repair(
     <= ``spec.cols`` columns — independently for every (bit-slice, row
     group) unit, since each is its own array and the cross-array merge is
     digital.  (This also bounds the planner: every gain matrix is at most
-    ``spare_cols x cols``, vmapped over the S x R units, so wide slabs —
-    e.g. a vocab-sized LM head — cost one small greedy pass per group
-    instead of one quadratic pass over all columns.)  Spares carry their
+    ``spare_cols x cols``, vmapped over the S x R x group units, so wide
+    slabs — e.g. a vocab-sized LM head — cost one small greedy pass per
+    group instead of one quadratic pass over all columns, in a program
+    whose size does not grow with the number of groups.)  Spares carry their
     own seeded stuck-at faults, write-verify pulse noise, drift and IR
     drop, so the plan never pretends a spare is perfect.  Returns None when
     the config provisions no repair.
@@ -277,32 +286,33 @@ def plan_repair(
     off_sp = _unit_view(spare_masks[1].astype(jnp.float32), spec.rows)
 
     sal0 = column_salience(target, primary_masks, spec)  # (N,)
-    units = units0
-    victim = jnp.full((S, R, B), -1, jnp.int32)
-    gather = jnp.broadcast_to(jnp.arange(N, dtype=jnp.int32), (S, R, N))
-    for g in range(n_groups):
-        n0, n1 = g * spec.cols, min((g + 1) * spec.cols, N)
-        n_g = n1 - n0
-        b0 = g * B_per
-        t_g = t_u[:, :, :, n0:n1]
-        # err_sp[s, r, b, v]: fault error of spare b's (s, r) unit holding
-        # logical column v's targets for that unit
-        err_sp = jnp.einsum(
-            "srkb,srkv->srbv", on_sp[:, :, :, b0 : b0 + B_per], cell_max - t_g
-        ) + jnp.einsum("srkb,srkv->srbv", off_sp[:, :, :, b0 : b0 + B_per], t_g)
-        sal_u, victim_u, gather_u = jax.vmap(_greedy_assign)(
-            units0[:, :, n0:n1].reshape(S * R, n_g),
-            err_sp.reshape(S * R, B_per, n_g),
-        )
-        victim_u = victim_u.reshape(S, R, B_per)
-        gather_u = gather_u.reshape(S, R, n_g)
-        victim = victim.at[:, :, b0 : b0 + B_per].set(
-            jnp.where(victim_u >= 0, victim_u + n0, -1)
-        )
-        gather = gather.at[:, :, n0:n1].set(
-            jnp.where(gather_u >= n_g, gather_u - n_g + N + b0, gather_u + n0)
-        )
-        units = units.at[:, :, n0:n1].set(sal_u.reshape(S, R, n_g))
+    # every column group at once: columns padded to n_groups * cols (padded
+    # columns have zero salience, so no spare ever moves one), spares
+    # split B -> (group, B_per); one vmapped greedy per (s, r, group) unit
+    C = spec.cols
+    pad = n_groups * C - N
+    t_g = jnp.pad(t_u, ((0, 0), (0, 0), (0, 0), (0, pad))).reshape(S, R, spec.rows, n_groups, C)
+    on_g = on_sp.reshape(S, R, spec.rows, n_groups, B_per)
+    off_g = off_sp.reshape(S, R, spec.rows, n_groups, B_per)
+    # err_sp[s, r, g, b, v]: fault error of group g's spare b's (s, r) unit
+    # holding logical column v's targets for that unit
+    err_sp = jnp.einsum("srkgb,srkgv->srgbv", on_g, cell_max - t_g) + jnp.einsum(
+        "srkgb,srkgv->srgbv", off_g, t_g
+    )
+    units_g = jnp.pad(units0, ((0, 0), (0, 0), (0, pad))).reshape(S * R * n_groups, C)
+    sal_u, victim_u, gather_u = jax.vmap(_greedy_assign)(
+        units_g, err_sp.reshape(S * R * n_groups, B_per, C)
+    )
+    col0 = (jnp.arange(n_groups, dtype=jnp.int32) * C)[:, None]  # group's first column
+    spare0 = (jnp.arange(n_groups, dtype=jnp.int32) * B_per)[:, None]  # its first spare
+    victim = jnp.where(
+        victim_u.reshape(S, R, n_groups, B_per) >= 0,
+        victim_u.reshape(S, R, n_groups, B_per) + col0, -1,
+    ).reshape(S, R, B)
+    gather_u = gather_u.reshape(S, R, n_groups, C)
+    gather = jnp.where(gather_u >= C, gather_u - C + N + spare0, gather_u + col0)
+    gather = gather.reshape(S, R, n_groups * C)[:, :, :N]
+    units = sal_u.reshape(S, R, n_groups * C)[:, :, :N]
 
     # Program the chosen targets into the spare block through the standard
     # write-verify pipeline (independent "spare_program" pulse keys), then
@@ -319,16 +329,10 @@ def plan_repair(
     spare_target = vt.reshape(S, R * spec.rows, B)[:, :K, :]
     key = dm._stage_key(cfg, dm.STAGE_SPARE_PROGRAM, tag)
     g = dm.write_verify_fixed(spare_target, spare_masks, key, spec, cfg)
-    parts = []
-    for gi in range(n_groups):
-        b0 = gi * B_per
-        n_end = min((gi + 1) * spec.cols, N)
-        parts.append(
-            dm.read_effective_codes(
-                g[:, :, b0 : b0 + B_per], spec, cfg, col_offset=n_end
-            )
-        )
-    g_spare = jnp.concatenate(parts, axis=2) if n_groups > 1 else parts[0]
+    # spare b of group gi sits at wordline position min((gi+1)*cols, N) + (b - gi*B_per)
+    group_end = np.minimum((np.arange(n_groups) + 1) * C, N)
+    offsets = np.repeat(group_end - np.arange(n_groups) * B_per, B_per)
+    g_spare = dm.read_effective_codes(g, spec, cfg, col_offset=offsets)
 
     w = _slice_weights(spec)
     return RepairPlan(

@@ -48,9 +48,6 @@ from repro.core.crossbar import CrossbarSpec, DEFAULT_SPEC, RADIX_BITS, RADIX_MA
 DEFAULT_BM = 128
 DEFAULT_BN = 128
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _schedule_tables(spec: CrossbarSpec, cfg: Optional[ADCConfig]):
     """Static per-(t, s) LSB shift and MSB detect tables (python ints)."""
@@ -331,7 +328,7 @@ def crossbar_vmm_pallas(
             pltpu.VMEM((bm, bn), jnp.int32),  # accumulator lo limb
             pltpu.VMEM((bm, bn), jnp.int32),  # ADC overflow clamp flags
         ],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -40,7 +40,6 @@ from repro.core.adc import ADCConfig
 from repro.core.crossbar import CrossbarSpec, DEFAULT_SPEC, RADIX_BITS, RADIX_MASK
 from repro.device.models import GEFF_FRAC_BITS
 from repro.kernels.crossbar_vmm import (
-    COMPILER_PARAMS,
     DEFAULT_BM,
     DEFAULT_BN,
     _pad_to,
@@ -82,9 +81,11 @@ def _noisy_kernel(
             lo_acc = acc_lo[...]
             flags = flag_ref[...]
             for s in range(S):
-                # grid-quantized cells keep this dot exact in f32 (module doc)
+                # grid-quantized cells keep this dot exact in f32 (module
+                # doc); their 10 significant bits need full f32 operands
                 raw = jax.lax.dot_general(
                     plane, g[s], (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
                     preferred_element_type=jnp.float32,
                 )
                 # ADC sampling: round-half-up to an integer code, saturating
@@ -190,7 +191,7 @@ def noisy_vmm_pallas(
             pltpu.VMEM((bm, bn), jnp.int32),  # accumulator lo limb
             pltpu.VMEM((bm, bn), jnp.int32),  # ADC overflow clamp flags
         ],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.crossbar_vmm import COMPILER_PARAMS
-
 IGATE_CLIP = 5.0
 
 
@@ -95,7 +93,7 @@ def slstm_scan_pallas(pre, r_z, r_i, r_f, r_o, c0, n0, h0, interpret: bool = Fal
             jax.ShapeDtypeStruct((B, H, dh), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((3, H, dh), jnp.float32)],
-        compiler_params=COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -511,6 +511,44 @@ def lookup_crossbar_artifact(name: str, shape) -> Optional[Any]:
     return _resolve_crossbar_artifact(name, shape)[1]
 
 
+def _programmed_linear_on_mesh(x: jnp.ndarray, art) -> jnp.ndarray:
+    """``programmed_linear(x, art)`` under the active mesh.
+
+    XLA cannot partition a Mosaic kernel, so on a multi-device mesh and
+    outside a ``shard_map`` body every device computes the whole projection
+    from replicated operands.  That is what the layout asks for only when it
+    shards no dense tensor (``ep_only``: the expert banks, the one sharded
+    axis, are served inside the EP ``shard_map`` bodies).  Under any other
+    layout it would all-gather sharded weights and activations, so it
+    raises.  Interpreted kernels (off-TPU, ``kernels.ops._auto_interpret``)
+    are plain XLA ops, which XLA partitions itself.
+    """
+    from repro.device import programmed as prog
+    from repro.kernels.ops import _auto_interpret
+
+    mesh = current_mesh()
+    if (
+        mesh is None
+        or mesh.size == 1
+        or _auto_interpret()
+        or jax.sharding.get_abstract_mesh().manual_axes
+    ):
+        return prog.programmed_linear(x, art)
+    sharded = sorted(
+        a for a in LOGICAL_RULES if a != "experts" and _resolve_axis(a, mesh) is not None
+    )
+    if sharded:
+        raise ValueError(
+            "programmed crossbar kernels on a multi-device mesh run outside "
+            "shard_map only on replicated operands, but this layout shards "
+            f"the logical axes {sharded}; serve it with layout='ep_only'"
+        )
+    return jax.shard_map(
+        prog.programmed_linear, mesh=mesh, in_specs=P(), out_specs=P(),
+        check_vma=False,
+    )(x, art)
+
+
 def crossbar_linear(
     x: jnp.ndarray,
     w: jnp.ndarray,
@@ -552,7 +590,7 @@ def crossbar_linear(
         # x passed as-is: programmed_linear offset-encodes in x.dtype before
         # casting, mirroring the fallback below op-for-op (pre-casting bf16
         # activations here would break bit-identity between the two paths)
-        return prog.programmed_linear(x, art).astype(x.dtype)
+        return _programmed_linear_on_mesh(x, art).astype(x.dtype)
 
     if _CROSSBAR.programmed is not None:
         if key is None:

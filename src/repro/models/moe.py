@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.configs.base import ModelConfig
 from repro.models.layers import (
@@ -317,7 +316,7 @@ def _moe_alltoall(params, x, cfg: ModelConfig, mesh, batch_axes):
         y = jnp.zeros_like(xf).at[tok_slot].add(contrib.astype(xf.dtype))
         return y.reshape(Bl, Sl, D)
 
-    return shard_map(
+    return jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -325,7 +324,7 @@ def _moe_alltoall(params, x, cfg: ModelConfig, mesh, batch_axes):
             aspecs,
         ),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["wi"], wg, params["wo"], arts)
 
 
@@ -435,7 +434,7 @@ def _moe_expert_tp(params, x, cfg: ModelConfig, mesh, batch_axes):
         y = jnp.zeros_like(xf).at[tok_slot].add(contrib.astype(xf.dtype))
         return y.reshape(Bl, Sl, Dl)
 
-    y = shard_map(
+    y = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -447,7 +446,7 @@ def _moe_expert_tp(params, x, cfg: ModelConfig, mesh, batch_axes):
             aspecs,
         ),
         out_specs=x_spec,
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["wi"], wg, params["wo"], arts)
     return y
 
@@ -542,7 +541,7 @@ def moe_ffn(params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
                 ).reshape(Bl, Sl, D)
             return jax.lax.psum(y, "model")
 
-        y = shard_map(
+        y = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(
@@ -550,7 +549,7 @@ def moe_ffn(params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
                 e_spec, aspecs,
             ),
             out_specs=x_spec,
-            check_rep=False,
+            check_vma=False,
         )(x, params["router"], params["wi"], wg, params["wo"], arts)
 
     if cfg.moe_shared_experts:

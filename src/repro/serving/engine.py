@@ -163,12 +163,12 @@ class ModelRunner:
         self.crossbar = self._program_crossbars(crossbar, spare_cols, restore_artifacts)
         if verify_coverage:
             self.verify_crossbar_coverage()
-        self._decode = jax.jit(
-            lambda p, t, pos, c: self._with_crossbar(
-                lambda: model_lib.decode_step(p, self.cfg, t, pos, c)
-            )
-        )
-        self._prefills: Dict[int, object] = {}
+        # the jitted steps take the programmed chip (``self.artifacts``) as
+        # their first argument, never as a trace-time constant: the
+        # executables hold no copy of its arrays, and a swapped chip reuses
+        # them while its static aux is unchanged
+        self.decode_fn = jax.jit(self._on_chip(model_lib.decode_step))
+        self.prefill_fn = jax.jit(self._on_chip(model_lib.prefill))
 
     # ------------------------------------------------------------------
     @property
@@ -459,24 +459,17 @@ class ModelRunner:
         return prog
 
     def _rebind(self, prog) -> None:
-        """Swap the served chip and rebuild every jitted step function.
+        """Swap the served chip.
 
-        Artifacts are *trace-time constants* inside the jitted prefill and
-        decode steps (the closures bind ``self.crossbar.programmed`` when
-        they trace) — mutating the crossbar mode alone would keep serving
-        the old chip out of the jit cache.  Dropping the wrappers forces a
-        retrace against the new binding; KV caches, slot state and pending
+        The jitted prefill and decode steps take the artifact arrays as an
+        argument, so the next call serves the new chip; it recompiles only
+        where the artifacts' static aux changed (``age`` advances
+        ``t_service_s``, which is aux).  KV caches, slot state and pending
         requests live in the scheduler layer and are untouched, so
         in-flight requests continue on the new chip at the next tick — the
         zero-downtime part of ``hot_swap``.
         """
         self.crossbar = dataclasses.replace(self.crossbar, programmed=prog)
-        self._decode = jax.jit(
-            lambda p, t, pos, c: self._with_crossbar(
-                lambda: model_lib.decode_step(p, self.cfg, t, pos, c)
-            )
-        )
-        self._prefills = {}
 
     def age(self, dt_s: float) -> None:
         """Advance every bound chip ``dt_s`` seconds of service.
@@ -588,24 +581,48 @@ class ModelRunner:
         self.hot_swap(directory)
         return target
 
-    def _with_crossbar(self, fn):
+    def _with_crossbar(self, fn, artifacts=None):
         """Run ``fn`` under the runner's mesh and crossbar mode, with the
         programmed model's name-keyed artifact table bound for the dynamic
         scope (works at jit trace time — lookups resolve by name, not by
-        leaf identity, so any congruent params tree serves).  With a mesh,
-        the model's shard_map EP/TP paths engage and their bodies rebind
-        rank-local artifact slices."""
+        leaf identity, so any congruent params tree serves).  ``artifacts``
+        (a ``ProgrammedModel.artifacts`` tree, traced inside a jitted step)
+        stands in for the bound chip's arrays.  With a mesh, the model's
+        shard_map EP/TP paths engage and their bodies rebind rank-local
+        artifact slices."""
         with contextlib.ExitStack() as stack:
             if self.mesh is not None:
                 from repro.models.layers import layout_overrides, use_mesh
 
                 stack.enter_context(use_mesh(self.mesh, layout_overrides(self.cfg)))
                 stack.enter_context(self.mesh)
-            if self.crossbar is not None:
-                stack.enter_context(crossbar_mode(self.crossbar))
-                if self.crossbar.programmed is not None:
-                    stack.enter_context(self.crossbar.programmed.bind())
+            mode = self.crossbar
+            if mode is not None:
+                if artifacts is not None:
+                    from repro.device.programmed import ProgrammedModel
+
+                    mode = dataclasses.replace(mode, programmed=ProgrammedModel(artifacts))
+                stack.enter_context(crossbar_mode(mode))
+                if mode.programmed is not None:
+                    stack.enter_context(mode.programmed.bind())
             return fn()
+
+    def _on_chip(self, step):
+        """``step(params, cfg, *args)`` as ``fn(artifacts, params, *args)``,
+        run under ``_with_crossbar`` with the traced artifacts bound.  The
+        wrapper keeps the step's name, which compile logs and profiles show."""
+        def fn(artifacts, params, *args):
+            return self._with_crossbar(lambda: step(params, self.cfg, *args), artifacts)
+
+        fn.__name__ = fn.__qualname__ = step.__name__
+        return fn
+
+    @property
+    def artifacts(self):
+        """The bound chip's artifact tree (``ProgrammedModel.artifacts``),
+        the first argument of ``decode_fn`` and ``prefill_fn``."""
+        prog = self.programmed
+        return None if prog is None else prog.artifacts
 
     # ------------------------------------------------------------------
     # Scheduler-facing surface: cache init, prefill-admit, decode, sample
@@ -614,15 +631,6 @@ class ModelRunner:
     def init_cache(self, batch: int, dtype=jnp.float32):
         """A dense slot-pool cache sized to this runner's ``max_seq``."""
         return model_lib.init_cache(self.cfg, batch, self.max_seq, dtype=dtype)
-
-    def _prefill_fn(self, bucket: int):
-        if bucket not in self._prefills:
-            def fn(params, tokens, cache):
-                return self._with_crossbar(
-                    lambda: model_lib.prefill(params, self.cfg, tokens, cache)
-                )
-            self._prefills[bucket] = jax.jit(fn)
-        return self._prefills[bucket]
 
     def check_prompt(self, prompt, truncate: bool) -> int:
         """Validate a prompt against ``max_seq``; returns the effective
@@ -666,8 +674,7 @@ class ModelRunner:
         # so the copy below never silently drops tokens the bookkeeping
         # would then point past
         prompt[0, :S] = req.prompt[:S]
-        small_cache = self.init_cache(1)
-        logits, filled = self._prefill_fn(bucket)(self.params, jnp.asarray(prompt), small_cache)
+        logits, filled = self.prefill(jnp.asarray(prompt), self.init_cache(1))
         cache = jax.tree.map(
             lambda big, one: big.at[:, slot].set(one[:, 0]), cache, filled
         )
@@ -678,11 +685,18 @@ class ModelRunner:
         # point at the last token that was actually prefilled
         return cache, S - 1, int(np.asarray(req.prompt)[S - 1]), None
 
+    def prefill(self, tokens, cache):
+        """One jitted prefill of ``tokens`` (B, S) into ``cache``; returns
+        ``(last_logits, cache)`` as device arrays."""
+        return self.prefill_fn(self.artifacts, self.params, tokens, cache)
+
     def decode(self, last_tok: np.ndarray, pos: np.ndarray, cache):
         """One jitted decode tick over the whole slot pool; returns
         ``(logits, cache)`` with logits as host float32."""
         toks = jnp.asarray(np.asarray(last_tok)[:, None])
-        logits, cache = self._decode(self.params, toks, jnp.asarray(pos), cache)
+        logits, cache = self.decode_fn(
+            self.artifacts, self.params, toks, jnp.asarray(pos), cache
+        )
         return np.asarray(logits, np.float32), cache
 
     def sample(self, logits: np.ndarray) -> np.ndarray:

@@ -105,7 +105,6 @@ def test_compressed_allreduce_error_feedback():
     res = _run("""
         import json, numpy as np, jax, jax.numpy as jnp
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.train.compression import ef_int8_psum
 
         mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
@@ -114,8 +113,8 @@ def test_compressed_allreduce_error_feedback():
         def step(x, err):
             return ef_int8_psum(x, err, "data")
 
-        f = shard_map(step, mesh=mesh, in_specs=(P("data"), P("data")),
-                      out_specs=(P("data"), P("data")), check_rep=False)
+        f = jax.shard_map(step, mesh=mesh, in_specs=(P("data"), P("data")),
+                      out_specs=(P("data"), P("data")), check_vma=False)
         err = jnp.zeros_like(g)
         true_mean = jnp.mean(g, axis=0, keepdims=True)
         # accumulated compressed means over T steps converge to T * true mean
