@@ -1,0 +1,36 @@
+"""Kernels: the ideal chip's crossbar kernel (``crossbar_vmm_pallas``)
+inside the decode step, as a share of its roofline.  For every kernel call
+of the ``jit_decode_step`` programs that ran whole inside the traced
+window, the least time the chip could take for the product's operations
+and bytes (``flops.kernel_cost``: 16-bit input codes, 4-byte outputs,
+2 bytes per 16-bit weight code, over the slot pool's rows), summed, over
+the kernels' summed device time.  A step counts only where the trace holds
+one kernel call per programmed projection; the reader reports nothing
+where no step does.  Moves itl_p95_ms."""
+import flops
+
+KERNEL = "crossbar_vmm_pallas"
+PROGRAM = "jit_decode_step"
+WEIGHT_BYTES = 2.0
+
+
+def read(ctx):
+    r = ctx.reduced
+    projections = flops.projections(ctx.dims)
+    kernels = sorted((o.start, o.end) for o in r.ops
+                     if o.name.split(".")[0] == KERNEL and o.program == PROGRAM)
+    steps, kernel_s = 0, 0.0
+    for p in r.programs:
+        if p.program != PROGRAM:
+            continue
+        inside = [b - a for a, b in kernels if p.start <= a and b <= p.end]
+        if len(inside) == len(projections):
+            steps += 1
+            kernel_s += sum(inside) / 1e9
+    if not steps:
+        return None
+    rows = ctx.cell.config["serving"]["max_batch"]
+    peak = flops.peaks(ctx.device_kind)
+    least = sum(flops.roofline_s(*flops.kernel_cost(rows, k, n, WEIGHT_BYTES), peak)
+                for _, k, n in projections)
+    return 100.0 * least * steps / kernel_s
