@@ -6,9 +6,10 @@ restarts/retraces.  Three statically checkable hazards:
 
 * **unseeded RNG** — a ``PRNGKey``/``default_rng`` whose seed is neither a
   literal nor derived from an identifier containing "seed" breaks replay;
-  module-level ``np.random.*`` samplers use hidden global state; and
-  ``time.time`` anywhere in ``src/`` injects wall clock (allowlisted for
-  the two telemetry sites that only *report* time).
+  module-level ``np.random.*`` samplers use hidden global state; and a
+  host clock read (``time.time``, ``perf_counter``, ``monotonic``, their
+  ``_ns`` forms) anywhere in ``src/`` injects time (allowlisted for the
+  sites that only *report* it).
 * **unpinned scale products** — PR 5 pinned FMA-contraction ULP flips by
   wrapping every product of two quantization scales in
   ``jax.lax.optimization_barrier`` (XLA may otherwise fuse
@@ -32,11 +33,17 @@ from repro.analysis.engine import (
 RULE_RNG = "determinism-rng"
 RULE_BARRIER = "determinism-barrier"
 
-# whole-file allowlist for wall-clock reads: these report time, they never
+# whole-file allowlist for host clock reads: these report time, they never
 # feed it into computation
 TIME_ALLOW: Dict[str, str] = {
     "src/repro/train/loop.py": "step-time telemetry in training metrics",
     "src/repro/launch/dryrun.py": "compile-walltime reporting",
+    "src/repro/launch/serve.py": "programming and serving time printed by the CLI",
+}
+
+# the host clocks of the ``time`` module
+CLOCKS = {
+    "time", "time_ns", "perf_counter", "perf_counter_ns", "monotonic", "monotonic_ns",
 }
 
 # np.random attributes that touch the hidden global generator
@@ -68,6 +75,15 @@ def _seed_ok(args: List[ast.AST]) -> bool:
             ):
                 return True
     return False
+
+
+def _clock_finding(relpath: str, line: int, clock: str) -> Finding:
+    return Finding(
+        RULE_RNG, relpath, line,
+        f"host clock `{clock}` in src/ — outputs must be a function of "
+        "(config, seed); allowlist reporting-only sites in "
+        "rules_determinism.TIME_ALLOW",
+    )
 
 
 def rule_rng(relpath: str, tree: ast.Module, source: str) -> List[Finding]:
@@ -104,14 +120,18 @@ def rule_rng(relpath: str, tree: ast.Module, source: str) -> List[Finding]:
                     f"`{dn}` uses numpy's hidden global RNG state — use a "
                     "seeded np.random.default_rng(seed) generator",
                 ))
-            elif dn.endswith("time.time") and relpath.startswith("src/"):
-                if relpath not in TIME_ALLOW:
-                    findings.append(Finding(
-                        RULE_RNG, relpath, node.lineno,
-                        "wall-clock `time.time` in src/ — outputs must be a "
-                        "function of (config, seed); allowlist telemetry-only "
-                        "sites in rules_determinism.TIME_ALLOW",
-                    ))
+            elif (
+                len(parts) >= 2 and parts[-2] == "time" and parts[-1] in CLOCKS
+                and relpath.startswith("src/") and relpath not in TIME_ALLOW
+            ):
+                findings.append(_clock_finding(relpath, node.lineno, dn))
+        elif (
+            isinstance(node, ast.ImportFrom) and node.module == "time"
+            and relpath.startswith("src/") and relpath not in TIME_ALLOW
+        ):
+            for alias in node.names:
+                if alias.name in CLOCKS:
+                    findings.append(_clock_finding(relpath, node.lineno, f"time.{alias.name}"))
     # dedupe attribute findings that also appear inside a flagged Call, and
     # repeated Name/Attribute walks of the same node chain
     uniq = {}

@@ -178,6 +178,7 @@ def noisy_vmm_pallas(
 
     out = pl.pallas_call(
         kernel,
+        name="noisy_vmm_pallas",  # the kernel's name in HLO and in profiles
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),

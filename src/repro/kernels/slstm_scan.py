@@ -76,6 +76,7 @@ def slstm_scan_pallas(pre, r_z, r_i, r_f, r_o, c0, n0, h0, interpret: bool = Fal
     st_spec = pl.BlockSpec((1, H, dh), lambda b, s: (b, 0, 0))
     out = pl.pallas_call(
         kernel,
+        name="slstm_scan_pallas",  # the kernel's name in HLO and in profiles
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 4, H, dh), lambda b, s: (b, s, 0, 0, 0)),

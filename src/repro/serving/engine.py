@@ -79,6 +79,7 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.models import model as model_lib
 from repro.models.layers import CrossbarMode, crossbar_mode
+from repro.serving import tracing
 
 
 @dataclasses.dataclass
@@ -669,18 +670,19 @@ class ModelRunner:
         # use buckets + an idempotent catch-up re-issue of token S-1.
         recurrent = self.cfg.family in ("ssm", "hybrid")
         bucket = S if recurrent else min(_bucket(S), self.max_seq)
-        prompt = np.zeros((1, bucket), np.int32)
-        # S <= bucket always (check_prompt clamps S to max_seq >= bucket),
-        # so the copy below never silently drops tokens the bookkeeping
-        # would then point past
-        prompt[0, :S] = req.prompt[:S]
-        logits, filled = self.prefill(jnp.asarray(prompt), self.init_cache(1))
-        cache = jax.tree.map(
-            lambda big, one: big.at[:, slot].set(one[:, 0]), cache, filled
-        )
-        if recurrent:
-            tok = int(self.sample(np.asarray(logits, np.float32))[0])
-            return cache, S, tok, tok
+        with tracing.span("runner.admit", rid=req.rid, slot=slot, prompt=S, bucket=bucket):
+            prompt = np.zeros((1, bucket), np.int32)
+            # S <= bucket always (check_prompt clamps S to max_seq >= bucket),
+            # so the copy below never silently drops tokens the bookkeeping
+            # would then point past
+            prompt[0, :S] = req.prompt[:S]
+            logits, filled = self.prefill(jnp.asarray(prompt), self.init_cache(1))
+            cache = jax.tree.map(
+                lambda big, one: big.at[:, slot].set(one[:, 0]), cache, filled
+            )
+            if recurrent:
+                tok = int(self.sample(np.asarray(logits, np.float32))[0])
+                return cache, S, tok, tok
         # pos/last_tok from the *effective* length: after truncation both
         # point at the last token that was actually prefilled
         return cache, S - 1, int(np.asarray(req.prompt)[S - 1]), None
@@ -692,21 +694,26 @@ class ModelRunner:
 
     def decode(self, last_tok: np.ndarray, pos: np.ndarray, cache):
         """One jitted decode tick over the whole slot pool; returns
-        ``(logits, cache)`` with logits as host float32."""
-        toks = jnp.asarray(np.asarray(last_tok)[:, None])
-        logits, cache = self.decode_fn(
-            self.artifacts, self.params, toks, jnp.asarray(pos), cache
-        )
-        return np.asarray(logits, np.float32), cache
+        ``(logits, cache)`` with logits as host float32.  The launch
+        returns before the device finishes; the fetch waits for it."""
+        with tracing.span("runner.launch"):
+            toks = jnp.asarray(np.asarray(last_tok)[:, None])
+            logits, cache = self.decode_fn(
+                self.artifacts, self.params, toks, jnp.asarray(pos), cache
+            )
+        with tracing.span("runner.fetch"):
+            logits = np.asarray(logits, np.float32)
+        return logits, cache
 
     def sample(self, logits: np.ndarray) -> np.ndarray:
-        if self.temperature <= 0.0:
-            return np.argmax(logits, axis=-1).astype(np.int32)
-        self.key, sub = jax.random.split(self.key)
-        g = jax.random.gumbel(sub, logits.shape)
-        return np.asarray(
-            jnp.argmax(logits / self.temperature + g, axis=-1), np.int32
-        )
+        with tracing.span("runner.sample"):
+            if self.temperature <= 0.0:
+                return np.argmax(logits, axis=-1).astype(np.int32)
+            self.key, sub = jax.random.split(self.key)
+            g = jax.random.gumbel(sub, logits.shape)
+            return np.asarray(
+                jnp.argmax(logits / self.temperature + g, axis=-1), np.int32
+            )
 
 
 class ServingEngine:

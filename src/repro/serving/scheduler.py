@@ -21,7 +21,9 @@ decode tick as a scheduling decision:
 Time is the tick counter — one decode step per tick — so every latency
 number the traffic bench reports is deterministic: no wall clock enters
 the scheduler (the determinism lint forbids it in src/), and a fixed
-(seed, arrival schedule) replays identically.
+(seed, arrival schedule) replays identically.  Each tick is a
+``serving.tracing`` span (``sched.step``); the tracer reads the clock for
+reporting only, and nothing here reads it back.
 
 Bit-exactness: with ample blocks, no deadlines and the same admission
 order, ``step()`` makes exactly the decisions ``ServingEngine.step()``
@@ -35,6 +37,7 @@ from __future__ import annotations
 from typing import Callable, Dict, List, Optional
 
 from repro.serving.engine import ModelRunner, Request
+from repro.serving import tracing
 from repro.serving.kvcache import BlockCacheConfig, BlockKVCache
 
 import numpy as np
@@ -144,15 +147,17 @@ class ContinuousBatchingScheduler:
         if req.on_token is not None:
             req.on_token(req, tok)
 
-    def _admit(self) -> None:
-        """EDF admission into free slots, charged against the block pool.
+    def _admit(self) -> int:
+        """EDF admission into free slots, charged against the block pool;
+        returns how many requests took a slot.
 
         A candidate that does not fit the pool is skipped (no head-of-line
         blocking); a previously preempted request resumes from its paged
         blocks without re-prefilling.
         """
         if not self.waiting:
-            return
+            return 0
+        admitted = 0
         for slot in range(self.max_batch):
             if self.slots[slot] is not None or not self.waiting:
                 continue
@@ -167,7 +172,7 @@ class ContinuousBatchingScheduler:
                     chosen = req
                     break
             if chosen is None:
-                return  # pool dry for every candidate; decode drains it
+                break  # pool dry for every candidate; decode drains it
             self.waiting.remove(chosen)
             if self.kv.is_paged(chosen.rid):
                 p, lt = self.kv.page_in(chosen.rid, slot)
@@ -184,6 +189,8 @@ class ContinuousBatchingScheduler:
                 if first is not None:
                     self._stream_tok(chosen, first)
             self.slots[slot] = chosen
+            admitted += 1
+        return admitted
 
     def _preempt(self, slot: int) -> None:
         """Swap a victim out exactly (freeing its blocks) and re-queue it."""
@@ -219,30 +226,32 @@ class ContinuousBatchingScheduler:
         """One scheduling tick: expire, admit, budget, decode, sample.
 
         Returns the number of slots advanced this tick."""
-        self._expire()
-        self._admit()
-        self._ensure_blocks()
-        active = [i for i in range(self.max_batch) if self.slots[i] is not None]
-        if not active:
+        with tracing.span("sched.step", tick=self.tick) as sp:
+            self._expire()
+            admitted = self._admit()
+            self._ensure_blocks()
+            active = [i for i in range(self.max_batch) if self.slots[i] is not None]
+            sp.set_metadata(rows=len(active), admitted=admitted)
+            if not active:
+                self.tick += 1
+                return 0
+            logits, self.kv.cache = self.runner.decode(self.last_tok, self.pos, self.kv.cache)
+            nxt = self.runner.sample(logits)
+            for i in active:
+                req = self.slots[i]
+                self.pos[i] += 1
+                tok = int(nxt[i])
+                self._stream_tok(req, tok)
+                self.last_tok[i] = tok
+                if (
+                    len(req.generated) >= req.max_new_tokens
+                    or (req.eos_id is not None and tok == req.eos_id)
+                    or self.pos[i] >= self.runner.max_seq - 1
+                ):
+                    self._finish(req, expired=False)
+                    self.slots[i] = None
             self.tick += 1
-            return 0
-        logits, self.kv.cache = self.runner.decode(self.last_tok, self.pos, self.kv.cache)
-        nxt = self.runner.sample(logits)
-        for i in active:
-            req = self.slots[i]
-            self.pos[i] += 1
-            tok = int(nxt[i])
-            self._stream_tok(req, tok)
-            self.last_tok[i] = tok
-            if (
-                len(req.generated) >= req.max_new_tokens
-                or (req.eos_id is not None and tok == req.eos_id)
-                or self.pos[i] >= self.runner.max_seq - 1
-            ):
-                self._finish(req, expired=False)
-                self.slots[i] = None
-        self.tick += 1
-        return len(active)
+            return len(active)
 
     def run(self, max_ticks: int = 10_000) -> List[Request]:
         """Drain the queue; returns completed + expired sorted by rid."""

@@ -124,6 +124,20 @@ def test_rng_rule_flags_unseeded_and_wall_clock():
                        rules=[rule_rng]) == []
 
 
+@pytest.mark.parametrize("read", [
+    "time.perf_counter()", "time.perf_counter_ns()", "time.monotonic()", "time.time_ns()",
+    "perf_counter()",
+])
+def test_rng_rule_flags_every_host_clock_in_src(read):
+    imports = "from time import perf_counter\n" if read == "perf_counter()" else "import time\n"
+    src = imports + f"t = {read}\n"
+    fs = lint_source("src/repro/serving/fake.py", src, rules=[rule_rng])
+    assert len(fs) == 1 and fs[0].rule == "determinism-rng"
+    # the allowlisted reporting sites and code outside src/ read clocks freely
+    for relpath in ("src/repro/train/loop.py", "src/repro/launch/serve.py", "bench/fake.py"):
+        assert lint_source(relpath, src, rules=[rule_rng]) == []
+
+
 def test_barrier_rule_flags_unpinned_two_scale_product():
     bad = "def f(x, x_scale, w_scale):\n    return x * (x_scale * w_scale)\n"
     fs = lint_source("src/repro/device/fake.py", bad, rules=[rule_barrier])
