@@ -17,9 +17,11 @@ Two kernels:
   (bm, 128) x (128, bn) per block, each a {0,1} x {0..3} product (exact in
   f32 by a large margin), with the ADC transform applied per conversion.
 * ``crossbar_vmm_fast`` — exact fused path when no ADC transform is needed
-  (full-resolution ADCs): splits activations into two 8-bit halves and does
-  2 x S = 16 dots per block; each dot's accumulator is bounded by
-  255 * 3 * 128 < 2**24, so f32 stays exact.
+  (full-resolution ADCs), where slices merge: activations split into two
+  8-bit halves, the biased weight code into byte-wide chunks of whole cells
+  (``fast_chunk_bits``), so 2 x 2 = 4 bf16 dots per block.  Bytes are exact
+  in bf16 and each dot's sum is at most 255 * 255 * 128 < 2**24, so the f32
+  accumulator is exact in any order.
 
 Grid is (M/bm, N/bn, K/bk) with bk = rows = 128 (the ADC row-group); the
 k axis is the innermost reduction ("arbitrary" semantics).  Both kernels are
@@ -184,12 +186,38 @@ def _requantize_block(o_ref, acc_hi, acc_lo, flag_ref, xsum_ref, spec: CrossbarS
     o_ref[...] = y.astype(jnp.int32)
 
 
-def _fast_kernel(x_ref, w_ref, xsum_ref, o_ref, acc_hi, acc_lo, flag_ref, *,
-                 spec: CrossbarSpec, n_k: int, skip_zero_planes: bool):
-    """Fused exact path: 2 activation halves x S slices = 16 dots/block.
+def fast_chunk_bits(spec: CrossbarSpec) -> Optional[int]:
+    """Width of the weight chunks the fast kernel multiplies at once.
 
-    ``skip_zero_planes`` predicates each activation half on its popcount —
-    small post-ReLU codes leave the high half all-zero, halving the dots.
+    The widest chunk of whole cells, at most 8 bits, whose dot with an input
+    half stays exact: operands up to 255 are exact in bf16, and a dot's sum
+    of nonnegative products stays below 2**24, so the f32 accumulator is
+    exact in any order: (2**half - 1) * (2**c - 1) * rows < 2**24.  8 for
+    ``DEFAULT_SPEC`` (two chunks).  None where no such chunk exists (an input
+    half or a cell over 8 bits, or too many rows).
+    """
+    half = spec.input_bits // 2
+    if half > 8:
+        return None
+    fits = [
+        c for c in range(spec.cell_bits, 9, spec.cell_bits)
+        if ((1 << half) - 1) * ((1 << c) - 1) * spec.rows < 1 << 24
+    ]
+    return max(fits, default=None)
+
+
+def _fast_kernel(x_ref, w_ref, xsum_ref, o_ref, acc_hi, acc_lo, flag_ref, *,
+                 spec: CrossbarSpec, n_k: int):
+    """Fused exact path: 2 input halves x byte-wide weight chunks per block.
+
+    With the full-resolution ADC the per-slice transform is the identity, so
+    slices merge: the biased weight code splits into ``fast_chunk_bits``-wide
+    chunks (two bytes by default: 4 bf16 dots per block, each bounded by
+    255 * 255 * 128 < 2**23).  Where the spec admits no such chunk, one f32
+    dot per slice.
+
+    No dot is predicated on its input half's popcount: the vector-to-scalar
+    branch costs more on the chip than all four dots of a block.
     """
     k = pl.program_id(2)
 
@@ -201,40 +229,40 @@ def _fast_kernel(x_ref, w_ref, xsum_ref, o_ref, acc_hi, acc_lo, flag_ref, *,
 
     x = x_ref[...]
     w = w_ref[...]
-    S = spec.n_slices
-    cell_mask = (1 << spec.cell_bits) - 1
+    c = fast_chunk_bits(spec)
+    dtype = jnp.bfloat16
+    if c is None:
+        c, dtype = spec.cell_bits, jnp.float32
+    chunks = [
+        ((w >> (j * c)) & ((1 << c) - 1)).astype(dtype)
+        for j in range(-(-spec.weight_bits // c))
+    ]
     half = spec.input_bits // 2
     hmask = (1 << half) - 1
-    for hx, xbits in ((0, (x & hmask)), (half, (x >> half) & hmask)):
-
-        def _accum(xbits=xbits, hx=hx):
-            xf = xbits.astype(jnp.float32)
-            hi_acc = acc_hi[...]
-            lo_acc = acc_lo[...]
-            for s in range(S):
-                sl = ((w >> (s * spec.cell_bits)) & cell_mask).astype(jnp.float32)
-                # 255 * 3 * 128 < 2**24: exact in f32
-                p = jax.lax.dot_general(
-                    xf, sl, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ).astype(jnp.int32)
-                base = hx + s * spec.cell_bits
-                if base < RADIX_BITS:
-                    # p < 2**17, so split before shifting to stay in int32:
-                    # p * 2**base = (p >> k) * 2**20 + (p & (2**k - 1)) * 2**base
-                    k_bits = RADIX_BITS - base
-                    hi_acc = hi_acc + (p >> k_bits)
-                    lo_acc = lo_acc + ((p & ((1 << k_bits) - 1)) << base)
-                else:
-                    hi_acc = hi_acc + (p << (base - RADIX_BITS))
-            carry = lo_acc >> RADIX_BITS
-            acc_hi[...] = hi_acc + carry
-            acc_lo[...] = lo_acc - (carry << RADIX_BITS)
-
-        if skip_zero_planes:
-            pl.when(jnp.any(xbits != 0))(_accum)
-        else:
-            _accum()
+    hi_acc = acc_hi[...]
+    lo_acc = acc_lo[...]
+    for hx, xbits in ((0, x & hmask), (half, (x >> half) & hmask)):
+        xo = xbits.astype(dtype)
+        for j, wc in enumerate(chunks):
+            p = jax.lax.dot_general(
+                xo, wc, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ).astype(jnp.int32)
+            base = hx + j * c
+            if base < RADIX_BITS:
+                # p < 2**24, so split before shifting to stay in int32:
+                # p * 2**base = (p >> k) * 2**20 + (p & (2**k - 1)) * 2**base.
+                # Each lo term is < 2**20: the lo limb stays below
+                # (1 + 2 * n_chunks) * 2**20 until the carry below.
+                k_bits = RADIX_BITS - base
+                hi_acc = hi_acc + (p >> k_bits)
+                lo_acc = lo_acc + ((p & ((1 << k_bits) - 1)) << base)
+            else:
+                # at most the block's total over 2**20: < 2**(acc_bits - 20)
+                hi_acc = hi_acc + (p << (base - RADIX_BITS))
+    carry = lo_acc >> RADIX_BITS
+    acc_hi[...] = hi_acc + carry
+    acc_lo[...] = lo_acc - (carry << RADIX_BITS)
 
     @pl.when(k == n_k - 1)
     def _finalize():
@@ -277,7 +305,8 @@ def crossbar_vmm_pallas(
 
     ``skip_zero_planes``: predicate each input bit-plane's dots on its
     popcount (``@pl.when``); bit-identical either way, faster on sparse
-    inputs.  ``core.crossbar.plane_activity`` counts the skipped
+    inputs.  The fast kernel has no such branch: on the chip the branch
+    costs more than its four dots.  ``core.crossbar.plane_activity`` counts the skipped
     conversions for the energy model.
     """
     batch_shape = x_codes.shape[:-1]
@@ -304,9 +333,7 @@ def crossbar_vmm_pallas(
     if fast:
         if adc_cfg is not None and adc_cfg.mode != "full":
             raise ValueError("fast path models full-resolution ADCs only")
-        kernel = functools.partial(
-            _fast_kernel, spec=spec, n_k=grid[2], skip_zero_planes=skip_zero_planes
-        )
+        kernel = functools.partial(_fast_kernel, spec=spec, n_k=grid[2])
     else:
         kernel = functools.partial(
             _vmm_kernel, spec=spec, shifts=shifts, detects=detects, n_k=grid[2],

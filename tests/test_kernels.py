@@ -10,9 +10,10 @@ import pytest
 from _propcheck import integers, sweep
 
 from repro.core import adc
-from repro.core.crossbar import CrossbarSpec, DEFAULT_SPEC
+from repro.core.crossbar import CrossbarSpec, DEFAULT_SPEC, layer_scaled_spec
 from repro.device import DeviceConfig, effective_cell_codes
 from repro.kernels import ops, ref
+from repro.kernels.crossbar_vmm import crossbar_vmm_pallas, fast_chunk_bits
 
 SPEC_S = DEFAULT_SPEC
 SPEC_U = DEFAULT_SPEC.replace(signed_weights=False)
@@ -45,13 +46,98 @@ def test_kernel_matches_ref_shapes(shape):
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
 
 
-@pytest.mark.parametrize("shape", [(4, 128, 16), (3, 300, 40)])
-def test_fast_kernel_matches_ref(shape):
-    rng = np.random.default_rng(sum(shape) + 1)
-    x, w = _data(rng, *shape)
-    y_k = ops.crossbar_vmm_op(x, w, SPEC_S, fast=True, interpret=True)
-    y_r = ref.crossbar_vmm_ref(x, w, SPEC_S)
+def _worst_case(rng, B, K, N, spec):
+    """Every input code at its top and every weight at one of its extremes:
+    the first column all at the top, so each of its dots reaches the bound."""
+    x = np.full((B, K), (1 << spec.input_bits) - 1)
+    lo = -(1 << (spec.weight_bits - 1)) if spec.signed_weights else 0
+    hi = lo + (1 << spec.weight_bits) - 1
+    w = np.where(rng.random((K, N)) < 0.5, lo, hi)
+    w[:, 0] = hi
+    return jnp.asarray(x), jnp.asarray(w)
+
+
+# (spec, B, K, N, data); the first two cases keep the old test's ids
+_FAST_CASES = {
+    "shape0": (SPEC_S, 4, 128, 16, "random"),
+    "shape1": (SPEC_S, 3, 300, 40, "random"),
+    "decode_m32": (layer_scaled_spec(SPEC_S, 128), 32, 128, 256, "random"),
+    "padded_k960": (layer_scaled_spec(SPEC_S, 960), 4, 960, 16, "random"),
+    "prefill_m768": (layer_scaled_spec(SPEC_S, 128), 768, 128, 16, "random"),
+    "worst_case": (layer_scaled_spec(SPEC_S, 256), 8, 256, 32, "worst"),
+    "worst_case_unsigned": (layer_scaled_spec(SPEC_U, 256), 8, 256, 32, "worst"),
+    "unsigned": (layer_scaled_spec(SPEC_U, 300), 3, 300, 40, "random"),
+    "rows512_chunk6": (layer_scaled_spec(SPEC_S.replace(rows=512), 600), 4, 600, 24, "worst"),
+    "cell1_chunk8": (layer_scaled_spec(SPEC_S.replace(cell_bits=1), 200), 4, 200, 24, "random"),
+    "cell3_chunk6": (layer_scaled_spec(SPEC_S.replace(cell_bits=3), 200), 4, 200, 24, "random"),
+    "input18_per_slice": (
+        layer_scaled_spec(SPEC_S.replace(input_bits=18), 200), 4, 200, 24, "random"
+    ),
+}
+
+
+@pytest.mark.parametrize("case", list(_FAST_CASES))
+def test_fast_kernel_matches_ref(case):
+    spec, B, K, N, data = _FAST_CASES[case]
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+    if data == "worst":
+        x, w = _worst_case(rng, B, K, N, spec)
+    else:
+        x = jnp.asarray(rng.integers(0, 1 << spec.input_bits, size=(B, K)))
+        lo = -(1 << (spec.weight_bits - 1)) if spec.signed_weights else 0
+        w = jnp.asarray(rng.integers(lo, lo + (1 << spec.weight_bits), size=(K, N)))
+    y_k = ops.crossbar_vmm_op(x, w, spec, fast=True, interpret=True)
+    y_r = ref.crossbar_vmm_ref(x, w, spec)
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_r))
+
+
+def _count_dots(jaxpr) -> int:
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == "dot_general"
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    n += _count_dots(inner)
+    return n
+
+
+def test_fast_chunk_width_bound():
+    """The weight chunk width keeps every fast-kernel dot exact: operands
+    at most 255 (exact in bf16) and each dot's sum below 2**24 (exact in the
+    f32 accumulator), and no wider chunk of whole cells would be."""
+
+    def bound(spec, c):
+        return ((1 << (spec.input_bits // 2)) - 1) * ((1 << c) - 1) * spec.rows
+
+    for rows in (16, 64, 128, 256, 512, 1024, 2048, 4096, 1 << 16):
+        for cell_bits in (1, 2, 3, 4, 8, 16):
+            for weight_bits in (4, 8, 12, 16):
+                for input_bits in (4, 8, 15, 16, 17, 18, 20):
+                    spec = CrossbarSpec(
+                        rows=rows, cell_bits=cell_bits, weight_bits=weight_bits,
+                        input_bits=input_bits,
+                    )
+                    c = fast_chunk_bits(spec)
+                    if c is None:  # per-slice f32 dots: no exact chunk exists
+                        assert (
+                            input_bits // 2 > 8 or cell_bits > 8
+                            or bound(spec, cell_bits) >= 1 << 24
+                        ), spec
+                        continue
+                    assert c % cell_bits == 0 and cell_bits <= c <= 8, spec
+                    assert input_bits // 2 <= 8, spec
+                    assert bound(spec, c) < 1 << 24, spec
+                    assert c + cell_bits > 8 or bound(spec, c + cell_bits) >= 1 << 24, spec
+    # the default datapath: two byte-wide chunks, 2 halves x 2 = 4 dots a block
+    assert fast_chunk_bits(DEFAULT_SPEC) == 8
+    x = jax.ShapeDtypeStruct((32, 960), jnp.int32)
+    w = jax.ShapeDtypeStruct((960, 5120), jnp.int32)
+    jaxpr = jax.make_jaxpr(
+        functools.partial(crossbar_vmm_pallas, spec=DEFAULT_SPEC, fast=True)
+    )(x, w)
+    assert _count_dots(jaxpr.jaxpr) == 4
 
 
 @pytest.mark.parametrize("cfg", [adc.SAFE_ADAPTIVE, adc.EXACT_ADAPTIVE])
