@@ -1,6 +1,32 @@
 """What every run shares: finding a cell's files by name, building the
 served program from a configuration file, the host spans and compile log
-the per-layer metrics read, and the device checks."""
+the per-layer metrics read, and the device checks.
+
+A configuration file (``bench/configs/<config>.json``) names its registry
+entry (``registry``), its plain reference (``reference``), and its sizes.
+The served program's ``ModelConfig`` (``program_config``) is the registry
+entry, then the published keys the file has (``PUBLISHED``), then the
+file's optional ``program`` object: ``ModelConfig`` field names with JSON
+values, ``stages`` written as a list of ``{"kinds": [...], "repeats": n,
+"moe": [...]}``.  A key of ``program`` that is no field is an error.  A
+file that gives no stages is served as ``n_layers`` attention layers.
+
+A reference module (``bench/references/<reference>.py``) imports nothing of
+the program and gives these names (``REFERENCE_INTERFACE``):
+
+* ``Dims``, whose ``Dims.from_config(cfg)`` reads the sizes from the file;
+* ``init_params(key, dims)``: seeded weights on the device, in the served
+  program's parameter tree;
+* ``forward(params, tokens, dims, bits, n_valid=None, attn_dtype=None)``:
+  logits at every position of ``tokens`` (B, S);
+* ``Bits``: the widths of the crossbar datapath, ``Bits()`` the served ones;
+* ``decode_kernels(dims, rows)``: ``(name, m, k, n)`` of every crossbar
+  kernel call of one decode step over ``rows`` slots, read by
+  ``crossbar_vmm_roofline.decode``;
+* ``decode_model_flops(dims, contexts)``: the model operations of one
+  decode step whose active rows attend over ``contexts`` positions, read
+  by ``decode_mfu``.
+"""
 from __future__ import annotations
 
 import collections
@@ -98,28 +124,51 @@ def device_config(cfg: Dict, seed: int):
     return DeviceConfig(**chip["device"], seed=seed % 2**31)
 
 
+# published key -> (ModelConfig field, type)
+PUBLISHED = {
+    "num_hidden_layers": ("n_layers", int),
+    "hidden_size": ("d_model", int),
+    "num_attention_heads": ("n_heads", int),
+    "num_key_value_heads": ("n_kv_heads", int),
+    "head_dim": ("head_dim", int),
+    "intermediate_size": ("d_ff", int),
+    "vocab_size": ("vocab_size", int),
+    "rope_theta": ("rope_theta", float),
+    "rms_norm_eps": ("norm_eps", float),
+    "tie_word_embeddings": ("tie_embeddings", bool),
+}
+
+
 def program_config(cfg: Dict):
     """The served program's ``ModelConfig`` from a configuration file: its
-    registry entry with the file's sizes."""
+    registry entry, the published keys the file has, then its ``program``
+    object (see the module's docstring)."""
     from repro.configs import get_config
-    from repro.configs.base import StageSpec
+    from repro.configs.base import ModelConfig, StageSpec
 
+    fields = {name: kind(cfg[key]) for key, (name, kind) in PUBLISHED.items() if key in cfg}
+    if "head_dim" not in cfg and {"hidden_size", "num_attention_heads"} <= set(cfg):
+        fields["head_dim"] = cfg["hidden_size"] // cfg["num_attention_heads"]
+    program = dict(cfg.get("program", {}))
+    unknown = set(program) - {f.name for f in dataclasses.fields(ModelConfig)}
+    if unknown:
+        raise KeyError(f"program keys that are no ModelConfig field: {sorted(unknown)}")
+    if "stages" in program:
+        program["stages"] = tuple(
+            StageSpec(kinds=tuple(s["kinds"]), repeats=int(s["repeats"]),
+                      moe=tuple(bool(m) for m in s.get("moe", ())))
+            for s in program["stages"])
+    fields.update(program)
     base = get_config(cfg["registry"])
-    L = cfg["num_hidden_layers"]
-    return dataclasses.replace(
-        base,
-        n_layers=L,
-        d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
-        d_ff=cfg["intermediate_size"],
-        vocab_size=cfg["vocab_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        norm_eps=float(cfg["rms_norm_eps"]),
-        tie_embeddings=bool(cfg["tie_word_embeddings"]),
-        stages=(StageSpec(kinds=("attn",), repeats=L),),
-    )
+    if "stages" not in fields:
+        fields["stages"] = (StageSpec(kinds=("attn",),
+                                      repeats=fields.get("n_layers", base.n_layers)),)
+    return dataclasses.replace(base, **fields)
+
+
+# the names every module under bench/references/ gives (module docstring)
+REFERENCE_INTERFACE = ("Dims", "init_params", "forward", "Bits", "decode_kernels",
+                       "decode_model_flops")
 
 
 class CompileLog:

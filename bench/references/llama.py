@@ -25,12 +25,16 @@ float32 digital forward (no crossbar).
 Everything else (RMSNorm, rotary embedding, grouped-query attention,
 SwiGLU, the tied head) follows the published Llama description in float32
 with ``jax.default_matmul_precision("highest")``.
+
+It also counts the work of one decode step of the served program
+(``decode_kernels``, ``decode_model_flops``), which the per-layer readers
+of the kernels' roofline and the step's MFU take from here.
 """
 from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, NamedTuple, Optional
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -59,7 +63,7 @@ class Dims(NamedTuple):
             d_model=cfg["hidden_size"],
             n_heads=cfg["num_attention_heads"],
             n_kv_heads=cfg["num_key_value_heads"],
-            head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
+            head_dim=cfg.get("head_dim", cfg["hidden_size"] // cfg["num_attention_heads"]),
             d_ff=cfg["intermediate_size"],
             vocab=cfg["vocab_size"],
             rope_theta=float(cfg["rope_theta"]),
@@ -272,3 +276,37 @@ def np_codes_product(xq: np.ndarray, wq: np.ndarray, drop: int, out_bits: int) -
     y = np.floor((s + 2.0 ** (drop - 1)) / 2.0 ** drop)
     lim = 1 << (out_bits - 1)
     return np.clip(y, -lim, lim - 1).astype(np.int64)
+
+
+# ---------------------------------------------------------------------------
+# Work of one decode step, counted from shapes
+# ---------------------------------------------------------------------------
+
+
+def projections(dims) -> List[Tuple[str, int, int]]:
+    """(name, K, N) of every programmed projection one token passes
+    through, the layers' repeated ``n_layers`` times, then the head."""
+    D, F = dims.d_model, dims.d_ff
+    q, kv = dims.n_heads * dims.head_dim, dims.n_kv_heads * dims.head_dim
+    layer = [("wq", D, q), ("wk", D, kv), ("wv", D, kv), ("wo", q, D),
+             ("wi", D, 2 * F), ("ffn_wo", F, D)]
+    return layer * dims.n_layers + [("head", D, dims.vocab)]
+
+
+def programmed_weights(dims) -> int:
+    return sum(k * n for _, k, n in projections(dims))
+
+
+def decode_kernels(dims, rows: int) -> List[Tuple[str, int, int, int]]:
+    """(name, M, K, N), one per crossbar kernel call of one decode step over
+    a pool of ``rows`` slots: every projection takes the whole pool."""
+    return [(name, rows, k, n) for name, k, n in projections(dims)]
+
+
+def decode_model_flops(dims, contexts: Sequence[int]) -> float:
+    """Model operations of one decode step whose active rows attend over
+    ``contexts`` positions each: 2 per programmed weight per row, and
+    4 * heads * head_dim per layer per position attended (scores and
+    values)."""
+    attn = 4.0 * dims.n_heads * dims.head_dim * dims.n_layers
+    return 2.0 * programmed_weights(dims) * len(contexts) + attn * float(sum(contexts))

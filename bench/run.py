@@ -100,6 +100,7 @@ def serve(runner, cell, holder, spans, planned, seconds: float, trace_dir: Optio
     w0 = float(cell.traffic["preroll_s"])
     w1 = w0 + seconds
     i = loadgen.drive(sched, planned, rec, w0, spans=spans)
+    at_open = (len(sched.waiting), sched.n_active)
     traced = None
     if trace_dir is None:
         loadgen.drive(sched, planned, rec, w1, i, spans=spans)
@@ -112,6 +113,8 @@ def serve(runner, cell, holder, spans, planned, seconds: float, trace_dir: Optio
         t_b = time.perf_counter()
         jax.profiler.stop_trace()
         traced = (t_a, t_b)
+    log(f"[window] waiting queue {at_open[0]} at open, {len(sched.waiting)} at close; "
+        f"slots in use {at_open[1]} at open, {sched.n_active} at close")
     return rec, sched, w0, w1, traced
 
 
@@ -175,7 +178,7 @@ def run(cell: harness.Cell, seed: int, seconds: float, trace: bool,
         profile = jax.profiler.ProfileData.from_file(trace_reduce.find_xplane(trace_dir))
         reduced = trace_reduce.reduce(profile)
         ctx = types.SimpleNamespace(
-            cell=cell, dims=dims, spans=spans.records, rec=rec, w0=w0, w1=w1,
+            cell=cell, dims=dims, ref=ref, spans=spans.records, rec=rec, w0=w0, w1=w1,
             traced=traced, reduced=reduced, split=split,
             device_kind=jax.devices()[0].device_kind)
         log(f"[trace] {reduced.window_s:.3f}s traced, device busy {reduced.busy_s:.3f}s; "

@@ -1,5 +1,6 @@
 """Operation and byte counts against hand-computed values at smollm-360m's
-widths, and the table of peaks."""
+widths (the Llama reference's counts of one decode step, ``flops``'s of
+one product), and the table of peaks."""
 import math
 
 import pytest
@@ -8,9 +9,11 @@ import flops
 import harness
 
 
+ref = harness.load_reference("llama")
+
+
 @pytest.fixture(scope="module")
 def dims():
-    ref = harness.load_reference("llama")
     return ref.Dims.from_config(harness.load_json(harness.BENCH / "configs" / "smollm-360m-ideal.json"))
 
 
@@ -19,7 +22,7 @@ def test_programmed_weights(dims):
     # ffn wo 2560x960; then the tied head 960x49152
     per_layer = 921_600 + 2 * 307_200 + 921_600 + 4_915_200 + 2_457_600
     assert per_layer == 9_830_400
-    assert flops.programmed_weights(dims) == 32 * per_layer + 47_185_920 == 361_758_720
+    assert ref.programmed_weights(dims) == 32 * per_layer + 47_185_920 == 361_758_720
 
 
 def test_kernel_cost_of_the_up_projection_at_32_rows():
@@ -37,15 +40,23 @@ def test_roofline_takes_the_larger_bound():
 
 
 def test_decode_step_cost_and_model_flops(dims):
-    costs = [flops.kernel_cost(32, k, n, 2.0) for _, k, n in flops.projections(dims)]
+    costs = [flops.kernel_cost(32, k, n, 2.0) for _, k, n in ref.projections(dims)]
     f, b = sum(c[0] for c in costs), sum(c[1] for c in costs)
     assert f == 2 * 32 * 361_758_720
     assert b == (2 * 32 * (32 * (4 * 960 + 960 + 2560) + 960)
                  + 4 * 32 * (32 * (960 + 2 * 320 + 960 + 5120 + 960) + 49152)
                  + 2 * 361_758_720)
     attn = 4 * 15 * 64 * 32
-    assert math.isclose(flops.decode_model_flops(dims, [10, 20]),
+    assert math.isclose(ref.decode_model_flops(dims, [10, 20]),
                         2 * 361_758_720 * 2 + attn * 30)
+
+
+def test_decode_kernels_are_the_projections_over_the_slot_pool(dims):
+    calls = ref.decode_kernels(dims, 32)
+    assert len(calls) == 6 * 32 + 1
+    assert calls == [(name, 32, k, n) for name, k, n in ref.projections(dims)]
+    assert calls[4] == ("wi", 32, 960, 5120) and calls[-1] == ("head", 32, 960, 49152)
+    assert sum(m * k * n for _, m, k, n in calls) == 32 * 361_758_720
 
 
 def test_unknown_device_kind_raises():

@@ -26,6 +26,12 @@ def test_every_metric_has_a_reader_and_every_listed_cell_exists():
         assert set(m.get("workloads", cells)) <= cells
 
 
+def test_every_per_layer_metric_moves_an_end_to_end_metric():
+    bench = harness.load_json(harness.ROOT / "BENCHMARK.json")
+    end_to_end = {m["name"] for m in bench["end_to_end"]}
+    assert all(m["moves"] in end_to_end for m in bench["per_layer"])
+
+
 def test_config_files_lie_under_paths_and_name_their_source():
     bench = harness.load_json(harness.ROOT / "BENCHMARK.json")
     for c in bench["configs"]:

@@ -8,11 +8,11 @@ import types
 import jax
 import pytest
 
-import flops
 import harness
 import trace_reduce
 
 TRACE = pathlib.Path(__file__).resolve().parent / "data" / "decode_trace.xplane.pb.gz"
+ref = harness.load_reference("llama")
 
 
 @pytest.fixture(scope="module")
@@ -25,7 +25,7 @@ def reduced():
 def dims():
     cfg = dict(harness.load_json(harness.BENCH / "configs" / "smollm-360m-ideal.json"),
                num_hidden_layers=2)
-    return harness.load_reference("llama").Dims.from_config(cfg)
+    return ref.Dims.from_config(cfg)
 
 
 def test_fixture_is_small():
@@ -47,8 +47,8 @@ def test_operations_exclude_containers_and_sum_below_busy(reduced):
     busy_ops = sum(o.end - o.start for o in reduced.ops) / 1e9
     assert busy_ops > 0.5 * reduced.busy_s
     kernels = [o for o in reduced.ops if o.name.startswith("crossbar_vmm_pallas")]
-    per_step = len(flops.projections(types.SimpleNamespace(
-        d_model=960, d_ff=2560, n_heads=15, n_kv_heads=5, head_dim=64, vocab=49152, n_layers=2)))
+    per_step = len(ref.decode_kernels(types.SimpleNamespace(
+        d_model=960, d_ff=2560, n_heads=15, n_kv_heads=5, head_dim=64, vocab=49152, n_layers=2), 8))
     assert sum(o.program == "jit_decode_step" for o in kernels) == 3 * per_step
 
 
@@ -66,11 +66,14 @@ def test_device_readers(reduced, dims):
     cfg = {"serving": {"max_batch": 8}}
     decode_calls = [(0.0, 1.0, {"contexts": [41]})] * 3
     ctx = types.SimpleNamespace(
-        reduced=reduced, dims=dims, cell=types.SimpleNamespace(config=cfg),
+        reduced=reduced, dims=dims, ref=ref, cell=types.SimpleNamespace(config=cfg),
         device_kind="TPU v5 lite", traced=(0.0, 1.0), spans={"decode": decode_calls})
     roof = harness.load_reader("crossbar_vmm_roofline.decode")(ctx)
     mfu = harness.load_reader("decode_mfu")(ctx)
     idle = harness.load_reader("idle_share")(ctx)
     assert 0 < roof <= 100 and 0 < mfu <= 100 and 0 <= idle < 100
+    # what the readers read on this trace when the work counts lived in
+    # flops.py: taking them from the reference changes no value
+    assert (roof, mfu, idle) == (6.023442077631438, 0.01425167410801466, 48.254759336987995)
     ctx.dims = dims._replace(n_layers=3)  # a kernel count that does not match
     assert harness.load_reader("crossbar_vmm_roofline.decode")(ctx) is None
