@@ -1,0 +1,5 @@
+"""``admit_ms``'s reading in the cells of a configuration with latent attention
+and experts (``mla-long-context``).  Moves itl_p95_ms."""
+import harness
+
+read = harness.load_reader("admit_ms")

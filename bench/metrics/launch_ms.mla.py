@@ -1,0 +1,5 @@
+"""``launch_ms``'s reading in the cells of a configuration with latent attention
+and experts (``mla-long-context``).  Moves itl_p95_ms."""
+import harness
+
+read = harness.load_reader("launch_ms")

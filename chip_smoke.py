@@ -148,7 +148,7 @@ def probe_logits(runner, tokens):
     digital forward of the same params (crossbar off, highest precision)."""
     from repro.models import model as model_lib
 
-    got, _ = runner.prefill(tokens, runner.init_cache(1))
+    got = runner.prefill(tokens, runner.init_cache(1))[0]
     cfg = runner.cfg
     with jax.default_matmul_precision("highest"):
         want, _ = jax.jit(lambda p, t, c: model_lib.prefill(p, cfg, t, c))(
@@ -248,7 +248,7 @@ def oracle_prefill(runner, tokens):
     # a new jitted function, so no trace of the kernel path is reused
     fn = jax.jit(runner._on_chip(model_lib.prefill))
     with mock.patch.object(noisy_vmm, "noisy_vmm_pallas", _noisy_oracle):
-        logits, _ = fn(runner.artifacts, runner.params, tokens, runner.init_cache(1))
+        logits = fn(runner.artifacts, runner.params, tokens, runner.init_cache(1))[0]
         return jax.block_until_ready(logits)
 
 

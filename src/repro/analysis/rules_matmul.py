@@ -14,7 +14,7 @@ audit table keyed by ``(relpath, ast.unparse(site))``:
   ``crossbar_linear`` itself.
 * ``known`` — a *known-digital projection*: a weight contraction that has
   not been lifted onto the programmed path yet (ROADMAP #5's ssm/xlstm
-  recurrent projections, MLA's absorbed W_uk/W_uv).  Reported as an
+  recurrent projections).  Reported as an
   ``info`` finding so the gap stays visible in every lint run instead of
   being folklore, but does not fail ``--check``.
 
@@ -113,18 +113,12 @@ AUDIT: Dict[str, Dict[str, Tuple[str, str]]] = {
             "allow", "weightless GQA attention scores"),
         "jnp.einsum('bqgrs,bsgd->bqgrd', p, v.astype(p.dtype))": (
             "allow", "weightless GQA value mix"),
-        "jnp.einsum('bqhl,bsl->bqhs', q_abs_blk.astype(latent_k.dtype), latent_k, preferred_element_type=jnp.float32)": (
+        "jnp.einsum('bqhl,bsl->bqhs', q_abs_blk.astype(latent_k.dtype), latent_k, preferred_element_type=jnp.float32, precision=HIGHEST)": (
             "allow", "weightless MLA scores vs cached latents"),
-        "jnp.einsum('bqhr,bsr->bqhs', q_rope_blk.astype(rope_k.dtype), rope_k, preferred_element_type=jnp.float32)": (
+        "jnp.einsum('bqhr,bsr->bqhs', q_rope_blk.astype(rope_k.dtype), rope_k, preferred_element_type=jnp.float32, precision=HIGHEST)": (
             "allow", "weightless MLA rope scores vs cached keys"),
-        "jnp.einsum('bqhs,bsl->bqhl', p.astype(latent_k.dtype), latent_k, preferred_element_type=jnp.float32)": (
+        "jnp.einsum('bqhs,bsl->bqhl', p.astype(latent_k.dtype), latent_k, preferred_element_type=jnp.float32, precision=HIGHEST)": (
             "allow", "weightless MLA latent value mix"),
-        "jnp.einsum('bshd,lhd->bshl', q_nope, params['w_uk'])": (
-            "known", "MLA absorbed W_uk projection runs digital "
-                     "(per-head low-rank absorb — ROADMAP #5 lift)"),
-        "jnp.einsum('bqhl,lhd->bqhd', ctx, params['w_uv'].astype(jnp.float32))": (
-            "known", "MLA absorbed W_uv projection runs digital "
-                     "(per-head low-rank absorb — ROADMAP #5 lift)"),
     },
     "src/repro/models/moe.py": {
         # digital fallback branch: runs only when current_crossbar().enabled
@@ -150,6 +144,9 @@ AUDIT: Dict[str, Dict[str, Tuple[str, str]]] = {
         "x @ w": (
             "allow", "the sanctioned dense fallback inside crossbar_linear "
                      "itself — guarded by the miss counter and strict="),
+        "jnp.einsum('g...k,gkn->g...n', x, w)": (
+            "allow", "crossbar-disabled digital branch of grouped_linear "
+                     "(guarded by _CROSSBAR.enabled)"),
     },
     "src/repro/models/model.py": {},
 }

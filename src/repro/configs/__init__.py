@@ -1,7 +1,8 @@
 """Architecture registry: one module per assigned architecture.
 
 ``get_config(name)`` returns the full config; ``reduced(cfg)`` builds the
-smoke-test variant.  ``ALL_ARCHS`` lists the ten assigned architectures.
+smoke-test variant.  ``ALL_ARCHS`` lists the ten assigned architectures; the registry also
+holds ``deepseek-v2-lite``, the benchmark's MLA + MoE configuration.
 """
 from repro.configs.base import (  # noqa: F401
     ModelConfig,
@@ -22,6 +23,7 @@ from repro.configs import gemma2_9b  # noqa: F401
 from repro.configs import minitron_4b  # noqa: F401
 from repro.configs import starcoder2_3b  # noqa: F401
 from repro.configs import deepseek_v2_236b  # noqa: F401
+from repro.configs import deepseek_v2_lite  # noqa: F401
 from repro.configs import kimi_k2_1t  # noqa: F401
 from repro.configs import pixtral_12b  # noqa: F401
 from repro.configs import jamba_52b  # noqa: F401

@@ -60,6 +60,11 @@ class ModelConfig:
     attn_softcap: float = 0.0  # gemma2 attention logit soft-capping
     logit_softcap: float = 0.0  # gemma2 final logit soft-capping
     attn_scale: Optional[float] = None  # override 1/sqrt(head_dim)
+    # YaRN rope scaling (deepseek-v2's ``rope_scaling``; beta_fast 32 and
+    # beta_slow 1, mscale equal to mscale_all_dim): factor 0 = plain rope
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_mscale_all_dim: float = 0.0
 
     # MLA (deepseek-v2)
     kv_lora_rank: int = 0
@@ -72,6 +77,13 @@ class ModelConfig:
     moe_shared_experts: int = 0
     moe_d_ff: int = 0
     moe_capacity_factor: float = 1.25
+    # experts 0..held-1 live on this chip (one EP rank's share); the router
+    # still scores all ``moe_experts``.  0 = all of them.
+    moe_experts_held: int = 0
+    # renormalize the top-k gates to sum 1; else the gates are the raw
+    # softmax probabilities (deepseek-v2's norm_topk_prob false, with
+    # routed_scaling_factor 1)
+    moe_norm_topk: bool = True
     # EP dispatch: "allreduce" (model-replicated tokens, local experts, psum
     # combine — no a2a) or "alltoall" (sequence-sharded tokens, GShard-style
     # all-to-all dispatch/combine — moves only routed tokens).  §Perf
@@ -93,6 +105,11 @@ class ModelConfig:
     tie_embeddings: bool = True
     embed_scale: bool = False  # gemma: scale embeddings by sqrt(d_model)
     frontend: str = "token"  # token | embed (audio/vlm stubs feed embeddings)
+
+    # programmed crossbars take each input row's range for its codes (a
+    # per-vector DAC range, ``CrossbarSpec.row_ranges``) instead of the
+    # whole call's, so a request's outputs do not depend on its batch
+    crossbar_row_ranges: bool = False
 
     # substrate preferences
     optimizer: str = "adamw"  # adamw | adafactor
@@ -144,6 +161,11 @@ class ModelConfig:
         for s in self.stages:
             flat.extend(list(s.moe) * s.repeats)
         return flat[i] if self.moe_experts else False
+
+    @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this chip holds."""
+        return self.moe_experts_held or self.moe_experts
 
     @property
     def q_dim(self) -> int:
@@ -250,6 +272,9 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         vocab_size=256,
         stages=stages,
         rope_theta=cfg.rope_theta,
+        yarn_factor=cfg.yarn_factor,
+        yarn_original_max_pos=cfg.yarn_original_max_pos,
+        yarn_mscale_all_dim=cfg.yarn_mscale_all_dim,
         sliding_window=min(cfg.sliding_window, 16) if cfg.sliding_window else 0,
         attn_softcap=cfg.attn_softcap,
         logit_softcap=cfg.logit_softcap,
@@ -259,6 +284,7 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
         moe_top_k=min(cfg.moe_top_k, 2) if cfg.moe_experts else 0,
         moe_shared_experts=min(cfg.moe_shared_experts, 1),
         moe_d_ff=64 if cfg.moe_experts else 0,
+        moe_norm_topk=cfg.moe_norm_topk,
         mamba_d_state=min(cfg.mamba_d_state, 8),
         mamba_d_conv=cfg.mamba_d_conv,
         mamba_d_inner=2 * d_model if cfg.mamba_d_inner else 0,

@@ -55,6 +55,11 @@ class CrossbarSpec:
     out_bits: int = 16
     drop_lsb: int = 10  # LSBs dropped by the output scaling stage
     signed_weights: bool = True
+    # input range (offset and scale of the input codes) taken per row, a
+    # per-vector DAC range, instead of over the whole call: a row's output
+    # then depends on that row alone (the programmed path,
+    # ``device.programmed.programmed_linear``, reads it)
+    row_ranges: bool = False
 
     @property
     def n_slices(self) -> int:

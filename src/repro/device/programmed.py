@@ -77,8 +77,8 @@ class ProgrammedLinear:
       * ``w_scale``: 0-d float32 — the frozen weight quantization scale (the
         ``max |w|`` reduction, paid once at programming time).
       * ``x_scale``: 0-d float32 or None — frozen input scale; None keeps
-        input quantization dynamic (per-call ``max(x)``), exactly matching
-        the unprogrammed path.
+        input quantization dynamic (per-call ``max(x)``, exactly matching
+        the unprogrammed path, or per row where ``spec.row_ranges``).
       * ``g_spare``: (S, K, B) float32 programmed spare-column cells, or
         None when the device provisions no repair (``device.repair``).
         ``g_eff`` already holds the *repaired* layout (spares scattered into
@@ -456,6 +456,13 @@ def age_artifact(art: ProgrammedLinear, dt_s: float) -> ProgrammedLinear:
     return artifact_at_time(art, art.t_service_s + float(dt_s))
 
 
+def _over_range(reduce, x: jnp.ndarray, spec: CrossbarSpec) -> jnp.ndarray:
+    """``reduce`` (``jnp.min``, ``jnp.max``) over the input's range: the
+    whole call, or each row (kept as a trailing axis of 1) where
+    ``spec.row_ranges``."""
+    return reduce(x, axis=-1, keepdims=True) if spec.row_ranges else reduce(x)
+
+
 def programmed_matmul(
     x: jnp.ndarray,
     art: ProgrammedLinear,
@@ -502,7 +509,7 @@ def programmed_matmul(
         # output ULP; bit-identity across eager/jit/shard_map is a contract
         # here (tests/test_sharded_artifacts.py pins it on an 8-rank mesh)
         x_scale = jax.lax.optimization_barrier(
-            jnp.maximum(jnp.max(x), 1e-9) / ((1 << spec.input_bits) - 1)
+            jnp.maximum(_over_range(jnp.max, x, spec), 1e-9) / ((1 << spec.input_bits) - 1)
         )
     xq = quantize_input(x, spec, x_scale)
     datapath = art.plan.datapath if art.plan is not None else "direct"
@@ -581,7 +588,7 @@ def programmed_linear(
     reconstitutes the full correction exactly — offset encoding decomposes
     over row blocks).
     """
-    shift = jnp.min(x)
+    shift = _over_range(jnp.min, x, art.spec)
     # barriers pin the rounding points of the offset-encode chain: without
     # them XLA is free to fuse the subtraction into the downstream quantize
     # divide (or the dequantize multiply and the correction into an FMA),
@@ -1009,15 +1016,18 @@ def active_artifact_for(
 
 
 # The projection leaves routed through models.layers.crossbar_linear — the
-# call sites that can consume an artifact: attention q/k/v/o and the MLA kv
-# down-projection, the dense-MLP wi/wo, the MoE expert bank wi/wg/wo plus
-# router and shared-expert projections, and the untied LM head.  (A tied LM
-# head serves from the transposed embedding artifact that
-# ``program_model(tie_lm_head=True)`` compiles under the embedding's name.)
+# call sites that can consume an artifact: attention q/k/v/o, the MLA kv
+# down-projection and its per-head absorbed up-projections, the dense-MLP
+# wi/wo, the MoE expert bank wi/wg/wo plus router and shared-expert
+# projections, and the untied LM head.  (A tied LM head serves from the
+# transposed embedding artifact that ``program_model(tie_lm_head=True)``
+# compiles under the embedding's name.)
 _CROSSBAR_CONSUMERS = (
-    "wq", "wk", "wv", "wo", "w_kv_down", "wi", "head",
+    "wq", "wk", "wv", "wo", "w_kv_down", "w_uk", "w_uv", "wi", "head",
     "wg", "router", "shared_wi", "shared_wg", "shared_wo",
 )
+# the 4-D leaves whose second axis is experts (the others stack heads)
+_EXPERT_BANKS = ("wi", "wg", "wo")
 
 
 def _path_names(path: Tuple[Any, ...]) -> Tuple[str, ...]:
@@ -1028,9 +1038,10 @@ def _matmul_leaf(path: Tuple[Any, ...], leaf: Any) -> bool:
     """Default predicate: which param leaves go onto crossbars.
 
     Allowlist of the projection names ``crossbar_linear`` actually serves
-    (attention q/k/v/o, the MLA kv down-projection, dense-MLP wi/wo, MoE
-    router/experts/shared experts, the untied LM head), as 2-D matrices,
-    3-D scan-stacked ``(L, K, N)``, or 4-D expert banks ``(L, E, K, N)``.
+    (attention q/k/v/o, the MLA kv down-projection and per-head W_uk/W_uv,
+    dense-MLP wi/wo, MoE router/experts/shared experts, the untied LM head),
+    as 2-D matrices, 3-D scan-stacked ``(L, K, N)``, or 4-D expert or head
+    banks ``(L, E, K, N)``.
     An allowlist — rather than excluding known non-matmuls — keeps stacked
     per-layer *vectors* (ssm ``conv_b``, ``D_skip``: ``(L, din)`` after
     stacking, indistinguishable from a small weight matrix by shape alone)
@@ -1236,6 +1247,7 @@ def program_model(
                 expert_chips is not None
                 and action is not None
                 and getattr(leaf, "ndim", 0) == 4
+                and _path_names(path)[-1] in _EXPERT_BANKS
             )
             else None
         )

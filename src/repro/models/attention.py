@@ -29,11 +29,16 @@ from repro.models.layers import (
     apply_rope,
     crossbar_linear,
     current_mesh,
+    grouped_linear,
     pspec,
+    rms_norm,
     shard,
     softcap,
+    yarn_inv_freq,
+    yarn_mscale,
 )
 
+HIGHEST = jax.lax.Precision.HIGHEST
 Q_CHUNK = 256  # bounds live scores at (B, 256, H, S); see EXPERIMENTS.md §Perf
 NEG_INF = -2.3819763e38  # most-negative bf16-representable-ish
 
@@ -71,8 +76,11 @@ def init_attention(ini: Init, cfg: ModelConfig):
     if cfg.kv_lora_rank:
         ini.param("wq", (d, h * (dh + cfg.qk_rope_dim)), ("embed", "heads"))
         ini.param("w_kv_down", (d, cfg.kv_lora_rank + cfg.qk_rope_dim), ("embed", None))
-        ini.param("w_uk", (cfg.kv_lora_rank, h, dh), (None, "heads", None))
-        ini.param("w_uv", (cfg.kv_lora_rank, h, dh), (None, "heads", None))
+        ini.param("kv_norm", (cfg.kv_lora_rank,), (None,), init="zeros")
+        # the per-head absorbed up-projections, head-major with K and N last:
+        # each head's slab is a crossbar projection of its own
+        ini.param("w_uk", (h, dh, cfg.kv_lora_rank), ("heads", None, None))
+        ini.param("w_uv", (h, cfg.kv_lora_rank, dh), ("heads", None, None))
         ini.param("wo", (h * dh, d), ("heads", "embed"))
     else:
         ini.param("wq", (d, h * dh), ("embed", "heads"))
@@ -249,19 +257,39 @@ def init_attention_cache(cfg: ModelConfig, batch: int, seq: int, dtype=jnp.bfloa
 # MLA (deepseek-v2) — absorbed form
 # ---------------------------------------------------------------------------
 
+def mla_rope(cfg: ModelConfig):
+    """(inv_freq or None, softmax scale) of MLA's decoupled rope: plain, or
+    YaRN as DeepSeek-V2 defines it, whose softmax scale grows by
+    ``yarn_mscale(factor, mscale_all_dim)**2``."""
+    scale = (cfg.head_dim + cfg.qk_rope_dim) ** -0.5
+    if not cfg.yarn_factor:
+        return None, scale
+    inv_freq = yarn_inv_freq(cfg.qk_rope_dim, cfg.rope_theta, cfg.yarn_factor,
+                             cfg.yarn_original_max_pos)
+    if cfg.yarn_mscale_all_dim:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return inv_freq, scale
+
+
 def _mla_block(params, x, cfg: ModelConfig, positions, cache, decode_pos):
     B, S, D = x.shape
     H, dh, rope_d, lora = cfg.n_heads, cfg.head_dim, cfg.qk_rope_dim, cfg.kv_lora_rank
-    scale = (dh + rope_d) ** -0.5
+    inv_freq, scale = mla_rope(cfg)
 
     q = crossbar_linear(x, params["wq"], name="wq").reshape(B, S, H, dh + rope_d)
     q = shard(q, "batch", None, "heads", None)
     q_nope, q_rope = q[..., :dh], q[..., dh:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, inv_freq)
 
     kvd = crossbar_linear(x, params["w_kv_down"], name="w_kv_down")  # (B, S, lora + rope)
-    latent, k_rope = kvd[..., :lora], kvd[..., lora:]
-    k_rope = apply_rope(k_rope[..., None, :], positions, cfg.rope_theta)[..., 0, :]
+    # the latent is RMS-normed (DeepSeek-V2's kv_a_layernorm) before it is
+    # used or cached, as a computation of its own on the materialized
+    # latent: fused into what makes or reads it, its sum of squares may add
+    # in another order, and the crossbars downstream round on the ulp
+    barrier = jax.lax.optimization_barrier
+    latent = barrier(rms_norm(barrier(kvd[..., :lora]), params["kv_norm"], cfg.norm_eps))
+    k_rope = kvd[..., lora:]
+    k_rope = apply_rope(k_rope[..., None, :], positions, cfg.rope_theta, inv_freq)[..., 0, :]
 
     if cache is not None:
         lc_dtype = cache["latent"].dtype
@@ -278,28 +306,32 @@ def _mla_block(params, x, cfg: ModelConfig, positions, cache, decode_pos):
         latent_c = shard_cache(latent_c)
         rope_c = shard_cache(rope_c)
         new_cache = {"latent": latent_c, "k_rope": rope_c}
-        latent_k, rope_k = latent_c, rope_c
-        Sk = latent_c.shape[1]
     else:
         new_cache = None
+    if cache is not None and decode_pos is not None:
+        latent_k, rope_k = latent_c, rope_c
+    else:
+        # a prefill starts at position 0: its own positions are all it sees
         latent_k, rope_k = latent, k_rope
-        Sk = S
+    Sk = latent_k.shape[1]
 
-    # Absorb W_uk into the query: q_abs (B, S, H, lora)
-    q_abs = jnp.einsum("bshd,lhd->bshl", q_nope, params["w_uk"])
+    # Absorb W_uk into the query, one crossbar projection per head:
+    # q_abs (B, S, H, lora)
+    q_abs = grouped_linear(q_nope.transpose(2, 0, 1, 3), params["w_uk"], "w_uk")
+    q_abs = q_abs.transpose(1, 2, 0, 3)
 
     pos_k = jnp.arange(Sk)
 
     def block(q_abs_blk, q_rope_blk, start, single_pos=None):
-        # bf16 operands + f32 accumulation: no f32 copy of the latent cache
-        # (halves decode cache-read bytes; MXU-native on TPU)
+        # operands in the cache's dtype, products at its full precision (the
+        # TPU's default rounds float32 operands to bfloat16), summed in f32
         s = jnp.einsum(
             "bqhl,bsl->bqhs", q_abs_blk.astype(latent_k.dtype), latent_k,
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=HIGHEST,
         )
         s = s + jnp.einsum(
             "bqhr,bsr->bqhs", q_rope_blk.astype(rope_k.dtype), rope_k,
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=HIGHEST,
         )
         s = s * scale
         if single_pos is None:
@@ -311,12 +343,11 @@ def _mla_block(params, x, cfg: ModelConfig, positions, cache, decode_pos):
             m = pos_k[None, :] <= pos_b[:, None]
             s = jnp.where(m[:, None, None, :], s, NEG_INF)
         p = jax.nn.softmax(s, axis=-1)
-        # attend over the latent, then up-project per head
-        ctx = jnp.einsum(
+        # attend over the latent; the per-head up-projection follows
+        return jnp.einsum(
             "bqhs,bsl->bqhl", p.astype(latent_k.dtype), latent_k,
-            preferred_element_type=jnp.float32,
+            preferred_element_type=jnp.float32, precision=HIGHEST,
         )
-        return jnp.einsum("bqhl,lhd->bqhd", ctx, params["w_uv"].astype(jnp.float32))
 
     if decode_pos is not None:
         out = block(q_abs, q_rope, 0, single_pos=decode_pos)
@@ -333,7 +364,10 @@ def _mla_block(params, x, cfg: ModelConfig, positions, cache, decode_pos):
             return None, jax.checkpoint(block)(qa_b, qr_b, idx * Q_CHUNK)
 
         _, outs = jax.lax.scan(body, None, (qa, qr, jnp.arange(nc)))
-        out = outs.transpose(1, 0, 2, 3, 4).reshape(B, S, H, dh)
+        out = outs.transpose(1, 0, 2, 3, 4).reshape(B, S, H, lora)
 
-    y = crossbar_linear(out.reshape(B, S, H * dh).astype(x.dtype), params["wo"], name="wo")
+    # W_uv, one crossbar projection per head over every position: (B, S, H, dh)
+    out = grouped_linear(out.transpose(2, 0, 1, 3).astype(x.dtype), params["w_uv"], "w_uv")
+    out = out.transpose(1, 2, 0, 3)
+    y = crossbar_linear(out.reshape(B, S, H * dh), params["wo"], name="wo")
     return shard(y, "batch", None, None), new_cache

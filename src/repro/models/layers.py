@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 import threading
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
@@ -303,10 +304,44 @@ def rope_freqs(head_dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x: (..., S, H, D) with positions (..., S) or (S,)."""
+def yarn_mscale(scale: float, m: float) -> float:
+    """DeepSeek-V2's ``yarn_get_mscale``: ``0.1 m ln(scale) + 1`` for a
+    context stretched ``scale`` times, 1 where it is not stretched."""
+    return 0.1 * m * math.log(scale) + 1.0 if scale > 1 else 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max_pos: int) -> np.ndarray:
+    """YaRN's rope frequencies, as DeepSeek-V2's reference code defines them
+    (``DeepseekV2YarnRotaryEmbedding``): the dimensions that turn more than
+    ``beta_fast`` = 32 times over the original context keep their
+    frequency, those that turn fewer than ``beta_slow`` = 1 times are
+    divided by ``factor``, with a linear ramp between
+    (``yarn_find_correction_range``, ``yarn_linear_ramp_mask``).  Its cos
+    and sin are scaled by ``yarn_mscale(factor, mscale) /
+    yarn_mscale(factor, mscale_all_dim)``, which is 1 where the two are
+    equal; both betas and the equality hold in every DeepSeek-V2 config."""
+    beta_fast, beta_slow = 32.0, 1.0
+
+    def correction_dim(rotations):
+        return (dim * math.log(original_max_pos / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    extra = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    keep = 1.0 - ramp  # 1 where the original frequency stays
+    return (extra / factor * (1.0 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               inv_freq=None) -> jnp.ndarray:
+    """x: (..., S, H, D) with positions (..., S) or (S,).  ``inv_freq``
+    (D/2,) replaces the plain frequencies (YaRN)."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)  # (D/2,)
+    freqs = rope_freqs(d, theta) if inv_freq is None else jnp.asarray(inv_freq)  # (D/2,)
     ang = positions.astype(jnp.float32)[..., None] * freqs  # (..., S, D/2)
     cos = jnp.cos(ang)[..., None, :]  # (..., S, 1, D/2)
     sin = jnp.sin(ang)[..., None, :]
@@ -613,3 +648,77 @@ def crossbar_linear(
     )
     corr = shift.astype(jnp.float32) * jnp.sum(w.astype(jnp.float32), axis=0)
     return (y + corr).astype(x.dtype)
+
+
+def scan_bound(body: Callable, weights: Dict[str, Any], xs: Any) -> Any:
+    """``body(x_g, w_g)`` for every group g along the leading axis of ``xs``
+    and of each of the named ``weights`` (None stays None), stacked.
+
+    Each group's weight slab is a projection of its own (an expert's, a
+    head's) and maps onto its own crossbars: where the group-stacked
+    artifacts that ``program_layer`` compiles from ``(L, G, K, N)`` leaves
+    are bound (layer-sliced by the stage scan), each group's slice is bound
+    under the same name for the body's ``crossbar_linear`` calls.  One scan
+    keeps the HLO size independent of the group count.
+    """
+    from repro.device.programmed import bind_artifacts
+
+    arts = {}
+    for n, w in weights.items():
+        art = None if w is None else lookup_crossbar_artifact(n, w.shape)
+        if art is not None:
+            arts[n] = art
+
+    def step(carry, inp):
+        xg, wg, ag = inp
+        with bind_artifacts(ag):
+            return carry, body(xg, wg)
+
+    _, y = jax.lax.scan(step, 0, (xs, weights, arts))
+    return y
+
+
+def grouped_linear(x: jnp.ndarray, w: jnp.ndarray, name: str) -> jnp.ndarray:
+    """``x[g] @ w[g]`` for every group g: x (G, ..., K), w (G, K, N) ->
+    (G, ..., N); on the crossbar each group is a projection of its own
+    (MLA's per-head absorbed ``W_uk`` / ``W_uv``, see ``scan_bound``)."""
+    if not _CROSSBAR.enabled:
+        return jnp.einsum("g...k,gkn->g...n", x, w)
+    return scan_bound(lambda xg, ws: crossbar_linear(xg, ws[name], name=name), {name: w}, x)
+
+
+# ---------------------------------------------------------------------------
+# Counters a traced step reports beside its outputs
+# ---------------------------------------------------------------------------
+#
+# ``record_count`` adds an int32 count (a scalar, or one per token) to the
+# innermost ``collect_counts`` scope and is a no-op outside one.  The
+# serving steps open a scope around the whole model (``ModelRunner``), and
+# the layer scan opens one per layer and returns its counts as scan
+# outputs, which the stage records summed over its layers
+# (``models.model._run_stage``): values traced inside a scan body can leave
+# it only that way.
+
+_COUNTS = threading.local()  # .stack: list of {name: int32 array}
+
+
+@contextlib.contextmanager
+def collect_counts():
+    stack = getattr(_COUNTS, "stack", None)
+    if stack is None:
+        stack = _COUNTS.stack = []
+    counts: Dict[str, jnp.ndarray] = {}
+    stack.append(counts)
+    try:
+        yield counts
+    finally:
+        stack.pop()
+
+
+def record_count(name: str, value) -> None:
+    stack = getattr(_COUNTS, "stack", None)
+    if not stack:
+        return
+    counts = stack[-1]
+    value = jnp.asarray(value, jnp.int32)
+    counts[name] = counts[name] + value if name in counts else value

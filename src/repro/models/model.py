@@ -30,12 +30,14 @@ from repro.models import ssm as ssm_mod
 from repro.models import xlstm as xlstm_mod
 from repro.models.layers import (
     Init,
+    collect_counts,
     current_crossbar,
     embed,
     init_embed,
     init_mlp,
     lm_head,
     mlp,
+    record_count,
     rms_norm,
     shard,
     softcap,
@@ -273,7 +275,7 @@ def _run_stage(
         # bind this layer's programmed-crossbar artifacts (scan-sliced in
         # lockstep with the params) so crossbar_linear serves steady-state;
         # keys are joined under the caller's "stage{i}" name scope
-        with bind_artifacts(ap):
+        with bind_artifacts(ap), collect_counts() as counts:
             new_entries = {}
             for i, kind in enumerate(spec.kinds):
                 entry = cache_layer[f"b{i}"] if cache_layer is not None else None
@@ -289,7 +291,8 @@ def _run_stage(
                 # sequence-parallel residual stream: the layer-boundary carries the
                 # scan backward must save shrink by the model-axis extent
                 h = shard(h, "batch", "act_seq", None)
-        return h, (new_entries if cache_layer is not None else None)
+        # each layer's counts leave the scan as outputs
+        return h, (new_entries if cache_layer is not None else None, counts)
 
     if remat:
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
@@ -307,11 +310,12 @@ def _run_stage(
             )
             x, ne = body(x, (lp, cl, ap))
             entries.append(ne)
-        if cache_stage is None:
-            return x, None
-        stacked = jax.tree.map(lambda *ys: jnp.stack(ys), *entries)
-        return x, stacked
-    x, new_cache = jax.lax.scan(body, x, (params_stage, cache_stage, artifacts_stage))
+        new_cache, counts = jax.tree.map(lambda *ys: jnp.stack(ys), *entries)
+    else:
+        x, (new_cache, counts) = jax.lax.scan(
+            body, x, (params_stage, cache_stage, artifacts_stage))
+    for name, per_layer in counts.items():
+        record_count(name, jnp.sum(per_layer, axis=0))
     return x, new_cache
 
 

@@ -34,13 +34,16 @@ from repro.models.layers import (
     current_mesh,
     lookup_crossbar_artifact,
     note_crossbar_gap,
+    record_count,
+    scan_bound,
     shard,
 )
 
 
 def init_moe(ini: Init, cfg: ModelConfig):
-    d, e, f = cfg.d_model, cfg.moe_experts, cfg.moe_d_ff
-    ini.param("router", (d, e), ("moe_dm", None), scale=0.02)
+    # the router scores every expert; the banks hold this chip's share
+    d, e, f = cfg.d_model, cfg.experts_held, cfg.moe_d_ff
+    ini.param("router", (d, cfg.moe_experts), ("moe_dm", None), scale=0.02)
     glu = cfg.mlp_kind in ("swiglu", "geglu")
     # up ("wi") and gate ("wg") projections are separate parameters so the
     # F dim can be TP-sharded (expert_tp layout) without the fused-GLU
@@ -97,36 +100,18 @@ def _expert_ffn_crossbar(h: jnp.ndarray, wi, wg, wo, kind: str) -> jnp.ndarray:
 
     Each expert's (D, F) / (F, D) projection is an independent weight slab
     and maps onto its own crossbars, so the batched einsum decomposes into
-    per-expert ``crossbar_linear`` calls — HLO size stays E-independent via
-    ``lax.scan``.  When expert-stacked programmed artifacts are bound for
-    this layer (the ``(E, K, N)`` banks ``program_layer`` compiles from 4-D
-    ``(L, E, K, N)`` leaves, layer-sliced by the stage scan), the scan
-    slices them per expert and rebinds, so every expert serves steady-state
-    from its own programmed chip; otherwise the per-call pipeline programs
-    each expert slice on the fly, exactly like any other unprogrammed
+    per-expert ``crossbar_linear`` calls, each serving from its expert's
+    slice of the expert-stacked artifacts where they are bound
+    (``layers.scan_bound``); otherwise the per-call pipeline programs each
+    expert slice on the fly, exactly like any other unprogrammed
     projection.
     """
-    from repro.device.programmed import bind_artifacts
+    def body(he, ws):
+        u = crossbar_linear(he, ws["wi"], name="wi")
+        g = crossbar_linear(he, ws["wg"], name="wg") if ws["wg"] is not None else None
+        return crossbar_linear(_act(u, g, kind), ws["wo"], name="wo")
 
-    arts = {}
-    for n, w in (("wi", wi), ("wg", wg), ("wo", wo)):
-        if w is None:
-            continue
-        art = lookup_crossbar_artifact(n, w.shape)  # expert-stacked (E, K, N)
-        if art is not None:
-            arts[n] = art
-
-    def body(carry, xs):
-        he, wie, wge, woe, arte = xs
-        with bind_artifacts(arte):
-            u = crossbar_linear(he, wie, name="wi")
-            g = crossbar_linear(he, wge, name="wg") if wge is not None else None
-            a = _act(u, g, kind)
-            ye = crossbar_linear(a, woe, name="wo")
-        return carry, ye
-
-    _, y = jax.lax.scan(body, 0, (h, wi, wg, wo, arts))
-    return y
+    return scan_bound(body, {"wi": wi, "wg": wg, "wo": wo}, h)
 
 
 # ---------------------------------------------------------------------------
@@ -228,13 +213,29 @@ def _route(x: jnp.ndarray, router_w: jnp.ndarray, cfg: ModelConfig):
     )
     probs = jax.nn.softmax(logits, axis=-1)
     gates, idx = jax.lax.top_k(probs, cfg.moe_top_k)
-    gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
+    if cfg.moe_norm_topk:
+        gates = gates / jnp.maximum(jnp.sum(gates, axis=-1, keepdims=True), 1e-9)
     return idx, gates.astype(x.dtype), probs
 
 
 def _capacity(n_tokens: int, cfg: ModelConfig, n_local_experts: int) -> int:
+    # a factor of moe_experts / moe_top_k gives n_tokens rows, which drops
+    # nothing: top-k picks distinct experts
     c = n_tokens * cfg.moe_top_k / max(1, cfg.moe_experts) * cfg.moe_capacity_factor
     return max(8, int(math.ceil(c / 8) * 8))
+
+
+def _record_dispatch(idx: jnp.ndarray, n_held: int, capacity: int) -> None:
+    """Count what one layer's dispatch does on this chip: per token, the
+    routed assignments that land on its held experts (``moe_held``, shaped
+    like the tokens, so that the runner can leave out the rows that carry
+    no request), the rows its expert projections compute (``moe_rows``),
+    and the held assignments past capacity, which contribute nothing
+    (``moe_dropped``)."""
+    record_count("moe_held", jnp.sum(idx < n_held, axis=-1))
+    record_count("moe_rows", n_held * capacity)
+    per_expert = jnp.bincount(idx.reshape(-1), length=n_held)
+    record_count("moe_dropped", jnp.sum(per_expert) - jnp.sum(jnp.minimum(per_expert, capacity)))
 
 
 def _dispatch_indices(top_idx, gates, n_experts: int, capacity: int):
@@ -462,6 +463,9 @@ def moe_ffn(params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
 
         if _resolve_axis("experts", mesh) is None and cfg.layout != "expert_tp":
             model_size = 1  # layout override: no EP
+    if cfg.experts_held != E and model_size > 1:
+        raise ValueError("a chip's share of the experts (moe_experts_held) is served "
+                         "without an expert-parallel mesh")
 
     if (
         cfg.layout == "expert_tp"
@@ -487,8 +491,11 @@ def moe_ffn(params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
         batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
         y = _moe_alltoall(params, shard(x, "batch", "act_seq", None), cfg, mesh, batch_axes)
     elif mesh is None or model_size == 1 or E % model_size != 0:
+        # experts 0..held-1 are this chip's; assignments to the others go
+        # to _dispatch_compute's overflow bucket and contribute nothing
         idx, gates, _ = _route(x, params["router"], cfg)
         cap = _capacity(B * S, cfg, E)
+        _record_dispatch(idx, cfg.experts_held, cap)
         y = _dispatch_compute(
             x.reshape(-1, D),
             idx.reshape(-1, cfg.moe_top_k),

@@ -70,7 +70,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import itertools
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -78,7 +78,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import model as model_lib
-from repro.models.layers import CrossbarMode, crossbar_mode
+from repro.models.layers import CrossbarMode, collect_counts, crossbar_mode
 from repro.serving import tracing
 
 
@@ -110,6 +110,19 @@ def _bucket(n: int, buckets=(32, 64, 128, 256, 512, 1024, 2048)) -> int:
         if n <= b:
             return b
     return -(-n // 2048) * 2048
+
+
+def _row_sums(counts: Dict[str, np.ndarray], rows) -> Dict[str, int]:
+    """A step's counts as host ints: a count kept per token summed over the
+    token rows ``rows`` selects (an index into the flattened tokens; None
+    takes them all), a scalar count as it is."""
+    out = {}
+    for k, v in counts.items():
+        v = np.asarray(v)
+        if v.ndim and rows is not None:
+            v = v.reshape(-1)[rows]
+        out[k] = int(np.sum(v))
+    return out
 
 
 class ModelRunner:
@@ -170,6 +183,11 @@ class ModelRunner:
         # them while its static aux is unchanged
         self.decode_fn = jax.jit(self._on_chip(model_lib.decode_step))
         self.prefill_fn = jax.jit(self._on_chip(model_lib.prefill))
+        # counts of admissions not yet read, and the slots that carry a
+        # request in the next decode, which its scheduler sets (see
+        # ``decode``)
+        self._admit_counts: List[Tuple[Dict, slice]] = []
+        self.active_slots: Optional[np.ndarray] = None
 
     # ------------------------------------------------------------------
     @property
@@ -317,10 +335,12 @@ class ModelRunner:
                         "nothing to repair"
                     )
                 crossbar = dataclasses.replace(crossbar, device=device)
+        from repro.core.crossbar import DEFAULT_SPEC
         from repro.device.programmed import program_model
 
         prog = program_model(
             self.params,
+            spec=DEFAULT_SPEC.replace(row_ranges=self.cfg.crossbar_row_ranges),
             device=device,
             fast=crossbar.fast,
             # tied LM heads serve from a transpose programmed once, bound to
@@ -610,10 +630,18 @@ class ModelRunner:
 
     def _on_chip(self, step):
         """``step(params, cfg, *args)`` as ``fn(artifacts, params, *args)``,
-        run under ``_with_crossbar`` with the traced artifacts bound.  The
-        wrapper keeps the step's name, which compile logs and profiles show."""
+        run under ``_with_crossbar`` with the traced artifacts bound, which
+        returns the step's outputs and then the counts the model recorded
+        (``models.layers.record_count``: ``{}`` for a model without experts).
+        The wrapper keeps the step's name, which compile logs and profiles
+        show."""
         def fn(artifacts, params, *args):
-            return self._with_crossbar(lambda: step(params, self.cfg, *args), artifacts)
+            def counted():
+                with collect_counts() as counts:
+                    out = step(params, self.cfg, *args)
+                return (*out, counts)
+
+            return self._with_crossbar(counted, artifacts)
 
         fn.__name__ = fn.__qualname__ = step.__name__
         return fn
@@ -676,7 +704,11 @@ class ModelRunner:
             # so the copy below never silently drops tokens the bookkeeping
             # would then point past
             prompt[0, :S] = req.prompt[:S]
-            logits, filled = self.prefill(jnp.asarray(prompt), self.init_cache(1))
+            logits, filled, counts = self.prefill(jnp.asarray(prompt), self.init_cache(1))
+            if counts:
+                # read with the next decode step's logits: no host sync here;
+                # the prompt's rows are the first S of the bucket
+                self._admit_counts.append((counts, slice(0, S)))
             cache = jax.tree.map(
                 lambda big, one: big.at[:, slot].set(one[:, 0]), cache, filled
             )
@@ -689,20 +721,34 @@ class ModelRunner:
 
     def prefill(self, tokens, cache):
         """One jitted prefill of ``tokens`` (B, S) into ``cache``; returns
-        ``(last_logits, cache)`` as device arrays."""
+        ``(last_logits, cache, counts)`` as device arrays."""
         return self.prefill_fn(self.artifacts, self.params, tokens, cache)
 
     def decode(self, last_tok: np.ndarray, pos: np.ndarray, cache):
         """One jitted decode tick over the whole slot pool; returns
         ``(logits, cache)`` with logits as host float32.  The launch
-        returns before the device finishes; the fetch waits for it."""
+        returns before the device finishes; the fetch waits for it.  The
+        step's counts, and those of the admissions since the last fetch
+        (``admit_<name>``, summed), come to the host with the logits and
+        go on the fetch span; a count kept per token is summed over the
+        slots ``active_slots`` marks (all of them where it is None) and
+        over each admission's prompt."""
         with tracing.span("runner.launch"):
             toks = jnp.asarray(np.asarray(last_tok)[:, None])
-            logits, cache = self.decode_fn(
+            logits, cache, counts = self.decode_fn(
                 self.artifacts, self.params, toks, jnp.asarray(pos), cache
             )
-        with tracing.span("runner.fetch"):
+        with tracing.span("runner.fetch") as sp:
+            logits, counts, admitted = jax.device_get(
+                (logits, counts, [c for c, _ in self._admit_counts]))
             logits = np.asarray(logits, np.float32)
+            meta = _row_sums(counts, self.active_slots)
+            for c, (_, rows) in zip(admitted, self._admit_counts):
+                for k, v in _row_sums(c, rows).items():
+                    meta[f"admit_{k}"] = meta.get(f"admit_{k}", 0) + v
+            self._admit_counts = []
+            if meta:
+                sp.set_metadata(**meta)
         return logits, cache
 
     def sample(self, logits: np.ndarray) -> np.ndarray:

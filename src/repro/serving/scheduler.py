@@ -235,6 +235,7 @@ class ContinuousBatchingScheduler:
             if not active:
                 self.tick += 1
                 return 0
+            self.runner.active_slots = np.array([s is not None for s in self.slots])
             logits, self.kv.cache = self.runner.decode(self.last_tok, self.pos, self.kv.cache)
             nxt = self.runner.sample(logits)
             for i in active:

@@ -7,7 +7,13 @@ sampling (``runner.sample``).  It is a
 ``jax.profiler.TraceAnnotation("repro.<name>", **attrs)``, so a profile
 captured of a running server holds it on the device trace's clock, its
 attrs as event stats; ``set_metadata(**attrs)`` adds attrs known only
-inside the span.  With no profiler running an annotation costs well under
+inside the span.  A model with experts puts its dispatch counts on
+``runner.fetch``, read from the device with the step's logits: for the
+decode step ``moe_held`` (routed assignments that landed on the experts
+this chip holds, summed over the MoE layers), ``moe_rows`` (the rows the
+held experts' projections computed) and ``moe_dropped`` (held assignments
+past capacity), and ``admit_moe_*`` for the admissions since the last
+fetch.  With no profiler running an annotation costs well under
 a microsecond.  Nothing here reads a clock: no decision of the scheduler
 or runner depends on a span, and tokens are the same with a profile
 captured or not.
