@@ -9,9 +9,11 @@ never materialized repeated.  MLA uses the *absorbed* form — scores are
 computed directly against the latent cache, so per-head K/V are never
 materialized (this is what makes deepseek-v2 prefill_32k fit).
 
-Cache sharding: ``shard_cache`` shards the batch dim over ("pod","data")
-when it divides, otherwise (long_500k, batch=1) shards the cache *sequence*
-dim — decode attention then reduces over a sharded axis and XLA inserts the
+A decode step writes each token in place on the stage's stacked cache
+through a ``LayerCache`` (see ``_cache_write``).  Cache sharding:
+``shard_cache`` shards the batch dim over ("pod","data") when it divides,
+otherwise (long_500k, batch=1) shards the cache *sequence* dim — decode
+attention then reduces over a sharded axis and XLA inserts the
 softmax-stable all-reduces.
 """
 from __future__ import annotations
@@ -43,9 +45,10 @@ Q_CHUNK = 256  # bounds live scores at (B, 256, H, S); see EXPERIMENTS.md §Perf
 NEG_INF = -2.3819763e38  # most-negative bf16-representable-ish
 
 
-def shard_cache(x: jnp.ndarray) -> jnp.ndarray:
-    """Shard (B, S, ...) caches: batch over ("pod","data") when divisible,
-    else sequence (long-context SP)."""
+def shard_cache(x: jnp.ndarray, batch_axis: int = 0) -> jnp.ndarray:
+    """Shard a cache array (B, S, ...), or a stage's layer stack with
+    ``batch_axis`` 1: its batch axis over ("pod","data") when divisible,
+    else the sequence axis after it (long-context SP)."""
     mesh = current_mesh()
     if mesh is None:
         return x
@@ -56,15 +59,14 @@ def shard_cache(x: jnp.ndarray) -> jnp.ndarray:
         resolved if isinstance(resolved, tuple) else (resolved,)
     )
     dp = int(np.prod([mesh.shape[a] for a in dp_axes])) if dp_axes else 1
-    rest = [None] * (x.ndim - 2)
-    b_entry = dividing_entry(x.shape[0], dp_axes, mesh) if dp > 1 and x.shape[0] > 1 else None
+    spec = [None] * x.ndim
+    nb = x.shape[batch_axis]
+    b_entry = dividing_entry(nb, dp_axes, mesh) if dp > 1 and nb > 1 else None
     if b_entry is not None:
-        spec = P(b_entry, None, *rest)
-    elif dp > 1 and x.shape[1] % dp == 0:
-        spec = P(None, dp_axes, *rest)
-    else:
-        spec = P(None, None, *rest)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+        spec[batch_axis] = b_entry
+    elif dp > 1 and x.shape[batch_axis + 1] % dp == 0:
+        spec[batch_axis + 1] = dp_axes
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*spec)))
 
 
 # ---------------------------------------------------------------------------
@@ -181,16 +183,78 @@ def decode_attention(q, k, v, pos, *, scale, window=0, attn_cap=0.0):
     return out.reshape(B, 1, H, dh).astype(q.dtype)
 
 
-def _cache_write(cache: jnp.ndarray, new: jnp.ndarray, pos) -> jnp.ndarray:
-    """Write one decode step into the cache at ``pos`` (scalar, or (B,) for
-    per-slot positions in continuous batching)."""
+# ---------------------------------------------------------------------------
+# Caches, written in place
+# ---------------------------------------------------------------------------
+#
+# A layer's cache holds the sequence on axis 1: (B, S, KV, dh) keys and
+# values, (B, S, lora) MLA latents and (B, S, 1, rope) MLA rope keys (one
+# key for every head, held like a key cache of one KV head).  The TPU
+# stores a cache whose minor axis is 64 wide with the sequence minor, and
+# the score einsums read that layout as it is stored; rope keys stacked
+# (L, B, S, rope) instead take a layout with the rope axis minor inside
+# the layer scan, and XLA relays out the whole stack at the step's entry
+# and exit.  A decode step writes its tokens with dynamic-update-slices,
+# which update the buffer in place in whatever layout it has (a scatter
+# has its operand relaid out with the token's features minor).
+
+
+def _cache_fill(cache: jnp.ndarray, new: jnp.ndarray) -> jnp.ndarray:
+    """A prompt's rows ``new`` (B, S, ...) written from position 0 of a
+    layer's cache."""
+    return jax.lax.dynamic_update_slice(cache, new.astype(cache.dtype), (0,) * cache.ndim)
+
+
+def _cache_write(cache: jnp.ndarray, new: jnp.ndarray, pos, layer=None) -> jnp.ndarray:
+    """Write one decode step's token ``new`` (B, 1, ...) at ``pos`` (scalar,
+    or (B,) for per-slot positions in continuous batching).
+
+    ``cache`` is one layer (B, S, ...), or with ``layer`` a stage's layer
+    stack (L, B, S, ...), written at that layer.  One dynamic-update-slice
+    per slot, or one for a scalar position, each in place.
+    """
     new = new.astype(cache.dtype)
+    lead = () if layer is None else (layer,)
+    rest = (0,) * (new.ndim - 2)
     pos = jnp.asarray(pos)
     if pos.ndim == 0:
-        start = (0, pos) + (0,) * (cache.ndim - 2)
-        return jax.lax.dynamic_update_slice(cache, new, start)
-    b = jnp.arange(cache.shape[0])
-    return cache.at[b, pos].set(new[:, 0])
+        return jax.lax.dynamic_update_slice(
+            cache, new[(None,) * len(lead)], lead + (0, pos) + rest)
+    for b in range(new.shape[0]):
+        # index the slot's row, then add the unit axes: slicing the stacked
+        # rows instead leaves XLA a layout for them that it carries back
+        # into the cache as whole-stack relayouts
+        row = new[b][(None,) * (len(lead) + 1)]
+        cache = jax.lax.dynamic_update_slice(cache, row, lead + (b, pos[b]) + rest)
+    return cache
+
+
+class LayerCache:
+    """One layer of a stage's decode cache, read from and written into the
+    stage's stacked buffer (the layer scan's carry).
+
+    ``view[name]`` reads the layer's array; ``view.write(name, new)``
+    writes one decode step's token at the slots' positions, in place on
+    the stack, and returns the layer as written; ``overwrite(state)``
+    writes a recurrent block's new state over the whole layer.  ``stack``
+    holds the stage's arrays as they stand.
+    """
+
+    def __init__(self, stack: Dict[str, jnp.ndarray], layer, pos):
+        self.stack, self.layer, self.pos = dict(stack), layer, pos
+
+    def __getitem__(self, name: str) -> jnp.ndarray:
+        return jax.lax.dynamic_index_in_dim(self.stack[name], self.layer, keepdims=False)
+
+    def write(self, name: str, new: jnp.ndarray) -> jnp.ndarray:
+        stack = _cache_write(self.stack[name], new, self.pos, self.layer)
+        self.stack[name] = shard_cache(stack, batch_axis=1)
+        return self[name]
+
+    def overwrite(self, state: Dict[str, jnp.ndarray]) -> None:
+        for name, a in state.items():
+            self.stack[name] = jax.lax.dynamic_update_index_in_dim(
+                self.stack[name], a.astype(self.stack[name].dtype), self.layer, 0)
 
 
 # ---------------------------------------------------------------------------
@@ -203,7 +267,7 @@ def attention_block(
     cfg: ModelConfig,
     kind: str,  # attn | attn_local | attn_global
     positions: jnp.ndarray,  # (S,) absolute positions
-    cache: Optional[Dict[str, jnp.ndarray]] = None,
+    cache=None,  # prefill: this layer's arrays; decode: its LayerCache
     decode_pos: Optional[jnp.ndarray] = None,
 ) -> Tuple[jnp.ndarray, Optional[Dict[str, jnp.ndarray]]]:
     if cfg.kv_lora_rank:
@@ -226,16 +290,17 @@ def attention_block(
     elif decode_pos is None:
         # prefill: attend within the prompt and return the filled cache
         out = gqa_attention(q, k, v, scale=scale, window=window, attn_cap=cfg.attn_softcap)
-        kc = shard_cache(jax.lax.dynamic_update_slice(cache["k"], k.astype(cache["k"].dtype), (0, 0, 0, 0)))
-        vc = shard_cache(jax.lax.dynamic_update_slice(cache["v"], v.astype(cache["v"].dtype), (0, 0, 0, 0)))
+        kc = shard_cache(_cache_fill(cache["k"], k))
+        vc = shard_cache(_cache_fill(cache["v"], v))
         new_cache = {"k": kc, "v": vc}
     else:
-        kc = shard_cache(_cache_write(cache["k"], k, decode_pos))
-        vc = shard_cache(_cache_write(cache["v"], v, decode_pos))
+        # decode: ``cache`` is a LayerCache; the token is written in place
+        # on the stage's stack, and the block returns no entry
+        kc = cache.write("k", k)
+        vc = cache.write("v", v)
         out = decode_attention(
             q, kc, vc, decode_pos, scale=scale, window=window, attn_cap=cfg.attn_softcap
         )
-        new_cache = {"k": kc, "v": vc}
 
     y = crossbar_linear(out.reshape(B, S, H * dh), params["wo"], name="wo")
     return shard(y, "batch", None, None), new_cache
@@ -245,7 +310,7 @@ def init_attention_cache(cfg: ModelConfig, batch: int, seq: int, dtype=jnp.bfloa
     if cfg.kv_lora_rank:
         return {
             "latent": jnp.zeros((batch, seq, cfg.kv_lora_rank), dtype),
-            "k_rope": jnp.zeros((batch, seq, cfg.qk_rope_dim), dtype),
+            "k_rope": jnp.zeros((batch, seq, 1, cfg.qk_rope_dim), dtype),
         }
     return {
         "k": jnp.zeros((batch, seq, cfg.n_kv_heads, cfg.head_dim), dtype),
@@ -291,25 +356,14 @@ def _mla_block(params, x, cfg: ModelConfig, positions, cache, decode_pos):
     k_rope = kvd[..., lora:]
     k_rope = apply_rope(k_rope[..., None, :], positions, cfg.rope_theta, inv_freq)[..., 0, :]
 
-    if cache is not None:
-        lc_dtype = cache["latent"].dtype
-        if decode_pos is None:
-            latent_c = jax.lax.dynamic_update_slice(
-                cache["latent"], latent.astype(lc_dtype), (0, 0, 0)
-            )
-            rope_c = jax.lax.dynamic_update_slice(
-                cache["k_rope"], k_rope.astype(lc_dtype), (0, 0, 0)
-            )
-        else:
-            latent_c = _cache_write(cache["latent"], latent, decode_pos)
-            rope_c = _cache_write(cache["k_rope"], k_rope, decode_pos)
-        latent_c = shard_cache(latent_c)
-        rope_c = shard_cache(rope_c)
-        new_cache = {"latent": latent_c, "k_rope": rope_c}
-    else:
-        new_cache = None
-    if cache is not None and decode_pos is not None:
-        latent_k, rope_k = latent_c, rope_c
+    new_cache = None
+    if cache is not None and decode_pos is None:
+        new_cache = {"latent": shard_cache(_cache_fill(cache["latent"], latent)),
+                     "k_rope": shard_cache(_cache_fill(cache["k_rope"], k_rope[:, :, None]))}
+    if decode_pos is not None:
+        # decode: written in place on the stage's stack (a LayerCache)
+        latent_k = cache.write("latent", latent)
+        rope_k = cache.write("k_rope", k_rope[:, :, None])[:, :, 0]
     else:
         # a prefill starts at position 0: its own positions are all it sees
         latent_k, rope_k = latent, k_rope

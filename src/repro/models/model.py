@@ -11,6 +11,9 @@ Entry points:
 Layers are stacked per stage on a leading axis and run under ``lax.scan``
 (with optional rematerialization), so HLO size is depth-independent — the
 multi-pod dry-run and the 1/2-layer roofline extrapolation rely on this.
+A decode step carries each stage's cache through its scan as one buffer
+and writes each token into it in place; the jitted serving step donates
+it, so no layer of the cache is copied.
 ``inp`` is int tokens (B, S) for ``frontend == "token"`` archs, or
 precomputed frame/patch embeddings (B, S, D) for the audio/vlm stubs.
 """
@@ -130,6 +133,11 @@ def _apply_block(
             h, new_entry = xlstm_mod.slstm_block(
                 params["mixer"], h, cfg, cache_entry, decode=decode_pos is not None
             )
+    if decode_pos is not None and new_entry is not None:
+        # a decoding block's cache is its LayerCache: attention writes its
+        # token through it, a recurrent block's new state goes over its layer
+        cache_entry.overwrite(new_entry)
+        new_entry = None
     if cfg.post_norm:
         h = rms_norm(h, params["norm1_post"], cfg.norm_eps)
     x = x + h
@@ -186,7 +194,7 @@ def cache_axes(cfg: ModelConfig):
             if cfg.kv_lora_rank:
                 return {
                     "latent": ("layers", "cache_batch", "cache_seq", None),
-                    "k_rope": ("layers", "cache_batch", "cache_seq", None),
+                    "k_rope": ("layers", "cache_batch", "cache_seq", None, None),
                 }
             return {
                 "k": ("layers", "cache_batch", "cache_seq", "kv_heads", None),
@@ -269,51 +277,64 @@ def _run_stage(
     remat: bool = False,
     artifacts_stage=None,
 ):
-    def body(carry, xs):
-        h = carry
-        lp, cache_layer, ap = xs
-        # bind this layer's programmed-crossbar artifacts (scan-sliced in
-        # lockstep with the params) so crossbar_linear serves steady-state;
-        # keys are joined under the caller's "stage{i}" name scope
+    def layer(h, lp, ap, entries):
+        # bind this layer's programmed-crossbar artifacts (sliced in lockstep
+        # with the params) so crossbar_linear serves steady-state; keys are
+        # joined under the caller's "stage{i}" name scope
         with bind_artifacts(ap), collect_counts() as counts:
             new_entries = {}
             for i, kind in enumerate(spec.kinds):
-                entry = cache_layer[f"b{i}"] if cache_layer is not None else None
                 with name_scope(f"b{i}"):
-                    h, ne = _apply_block(
+                    h, new_entries[f"b{i}"] = _apply_block(
                         lp[f"b{i}"], h, cfg, kind,
                         bool(spec.moe[i]) and cfg.moe_experts > 0,
-                        positions, entry, decode_pos,
+                        positions, entries[f"b{i}"] if entries is not None else None,
+                        decode_pos,
                     )
-                if cache_layer is not None:
-                    new_entries[f"b{i}"] = ne
             if decode_pos is None and h.shape[1] > 1:
                 # sequence-parallel residual stream: the layer-boundary carries the
                 # scan backward must save shrink by the model-axis extent
                 h = shard(h, "batch", "act_seq", None)
-        # each layer's counts leave the scan as outputs
-        return h, (new_entries if cache_layer is not None else None, counts)
+        return h, new_entries, counts
+
+    if decode_pos is not None:
+        # decode: the stage's cache is one buffer in the carry, and the scan
+        # runs over layer indices.  Each block writes into its layer in place
+        # through a LayerCache.  Each layer's counts leave the scan as outputs.
+        def body(carry, xs):
+            h, stage = carry
+            lp, li, ap = xs
+            views = {b: attn_mod.LayerCache(c, li, decode_pos) for b, c in stage.items()}
+            h, _, counts = layer(h, lp, ap, views)
+            return (h, {b: v.stack for b, v in views.items()}), counts
+
+        carry, xs = (x, cache_stage), (params_stage, jnp.arange(spec.repeats), artifacts_stage)
+    else:
+        # forward and prefill: each layer's cache entry (prefill) is scanned
+        # in and its filled entry scanned out, with its counts
+        def body(h, xs):
+            lp, cache_layer, ap = xs
+            h, new_entries, counts = layer(h, lp, ap, cache_layer)
+            return h, (new_entries if cache_layer is not None else None, counts)
+
+        carry, xs = x, (params_stage, cache_stage, artifacts_stage)
 
     if remat:
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
-    if not cfg.scan_layers:
+    if cfg.scan_layers:
+        carry, ys = jax.lax.scan(body, carry, xs)
+    else:
         # unrolled path (roofline depth variants): every layer appears in the
         # HLO so cost_analysis counts true totals
-        entries = []
+        outs = []
         for r in range(spec.repeats):
-            lp = jax.tree.map(lambda a: a[r], params_stage)
-            cl = jax.tree.map(lambda a: a[r], cache_stage) if cache_stage is not None else None
-            ap = (
-                jax.tree.map(lambda a: a[r], artifacts_stage)
-                if artifacts_stage is not None
-                else None
-            )
-            x, ne = body(x, (lp, cl, ap))
-            entries.append(ne)
-        new_cache, counts = jax.tree.map(lambda *ys: jnp.stack(ys), *entries)
+            carry, y = body(carry, jax.tree.map(lambda a: a[r], xs))
+            outs.append(y)
+        ys = jax.tree.map(lambda *y: jnp.stack(y), *outs)
+    if decode_pos is not None:
+        (x, new_cache), counts = carry, ys
     else:
-        x, (new_cache, counts) = jax.lax.scan(
-            body, x, (params_stage, cache_stage, artifacts_stage))
+        x, (new_cache, counts) = carry, ys
     for name, per_layer in counts.items():
         record_count(name, jnp.sum(per_layer, axis=0))
     return x, new_cache

@@ -180,8 +180,11 @@ class ModelRunner:
         # the jitted steps take the programmed chip (``self.artifacts``) as
         # their first argument, never as a trace-time constant: the
         # executables hold no copy of its arrays, and a swapped chip reuses
-        # them while its static aux is unchanged
-        self.decode_fn = jax.jit(self._on_chip(model_lib.decode_step))
+        # them while its static aux is unchanged.  The decode step donates
+        # its cache (``fn(artifacts, params, tokens, pos, cache)``): the step
+        # writes each token into it in place, and every caller replaces its
+        # cache with the one returned
+        self.decode_fn = jax.jit(self._on_chip(model_lib.decode_step), donate_argnums=4)
         self.prefill_fn = jax.jit(self._on_chip(model_lib.prefill))
         # counts of admissions not yet read, and the slots that carry a
         # request in the next decode, which its scheduler sets (see
@@ -726,7 +729,8 @@ class ModelRunner:
 
     def decode(self, last_tok: np.ndarray, pos: np.ndarray, cache):
         """One jitted decode tick over the whole slot pool; returns
-        ``(logits, cache)`` with logits as host float32.  The launch
+        ``(logits, cache)`` with logits as host float32.  ``cache`` is
+        donated: use the returned one.  The launch
         returns before the device finishes; the fetch waits for it.  The
         step's counts, and those of the admissions since the last fetch
         (``admit_<name>``, summed), come to the host with the logits and

@@ -2,7 +2,9 @@
 
 The crossbar kernels compile with ``interpret=False`` at the full-width
 shapes of smollm-360m, and a 2-layer full-width decode step compiles with
-the programmed chip passed as an argument.  With the artifacts baked into
+the programmed chip passed as an argument.  The decode steps of smollm-360m
+and of the DeepSeek-V2-Lite cell write their caches in place, with no
+layer of the cache copied.  With the artifacts baked into
 the executable as constants, that step's generated code is ~270 MB; as
 arguments it is a few MB.  The expert-parallel decode step that
 ``chip_smoke.py --chips 4`` serves compiles for the four chips of a 2x2
@@ -13,6 +15,8 @@ only one process at a time may load the TPU library, and every test worker
 imports this file.  Where it cannot be described, the tests skip.
 """
 from __future__ import annotations
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -85,7 +89,9 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, m, k, n):
     assert f"%{name}." in text
 
 
-def test_full_width_decode_step_takes_chip_as_argument(one_chip, monkeypatch):
+def _compiled_decode_step(cfg, one_chip, batch, max_seq, monkeypatch):
+    """``ModelRunner.decode_fn`` for ``cfg`` with an abstract ideal chip,
+    compiled for one described v5e; returns (compiled, abstract cache)."""
     from repro.device.programmed import ProgrammedModel, program_model
     from repro.kernels import ops
     from repro.models import model as model_lib
@@ -94,26 +100,119 @@ def test_full_width_decode_step_takes_chip_as_argument(one_chip, monkeypatch):
 
     # the backend here is the CPU; the step is compiled for the TPU
     monkeypatch.setattr(ops, "_auto_interpret", lambda: False)
-    cfg = with_depth(get_config("smollm-360m"), 2)
     params = model_lib.init_model(
         jax.random.PRNGKey(0), cfg, dtype=jnp.float32, shape_only=True
     )[0]
-    arts = jax.eval_shape(lambda p: program_model(p, tie_lm_head=True).artifacts, params)
+    arts = jax.eval_shape(
+        lambda p: program_model(p, tie_lm_head=cfg.tie_embeddings).artifacts, params
+    )
     runner = ModelRunner(
-        cfg, params, max_seq=512, verify_coverage=False,
+        cfg, params, max_seq=max_seq, verify_coverage=False,
         crossbar=CrossbarMode(enabled=True, strict=True, programmed=ProgrammedModel(arts)),
     )
-    batch = 8
+    cache = jax.eval_shape(lambda: runner.init_cache(batch))
     compiled = runner.decode_fn.lower(
         _on(one_chip, arts),
         _on(one_chip, params),
         jax.ShapeDtypeStruct((batch, 1), jnp.int32, sharding=one_chip),
         jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=one_chip),
-        _on(one_chip, jax.eval_shape(lambda: runner.init_cache(batch))),
+        _on(one_chip, cache),
     ).compile()
+    return compiled, cache
+
+
+def test_full_width_decode_step_takes_chip_as_argument(one_chip, monkeypatch):
+    cfg = with_depth(get_config("smollm-360m"), 2)
+    compiled, _ = _compiled_decode_step(cfg, one_chip, 8, 512, monkeypatch)
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "%crossbar_vmm_pallas." in text
     assert compiled.memory_analysis().generated_code_size_in_bytes < 64 * 2**20
+
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\([^=]*?\)|\w+\[[\d,]*\]\{[^}]*\}) ([\w-]+)\((.*)")
+_ARRAY = re.compile(r"(\w+)\[([\d,]*)\]\{([^}]*)\}")
+# what a decode step may do with an array the size of a layer's cache
+# without copying it: pass it on, view it, or write a token into it
+_PASS = {"parameter", "get-tuple-element", "bitcast", "tuple"}
+
+
+def _cache_sized_copies(text: str, batch: int, seq: int, min_elems: int, staged=()):
+    """Instructions of the step's unfused computations (the entry, the layer
+    scan's body) that yield a fresh array the size of a layer's cache: one
+    whose dims hold ``batch`` and ``seq`` and at least ``min_elems``
+    elements.  A dynamic-update-slice whose update is smaller writes in
+    place.  An asynchronous copy of an array whose dims are one of
+    ``staged``, in its own layout into or out of another memory space, is
+    memory-space assignment staging that array in on-chip memory."""
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.-]+)", text))
+    arrays, found, comp = {}, [], None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            comp = head.group(1)
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, typ, op, rest = m.groups()
+        shapes = _ARRAY.findall(typ)
+        arrays[name] = shapes
+        if comp in fused or op in _PASS:
+            continue
+        dims = [int(d) for d in shapes[0][1].split(",") if d] if shapes else []
+        if not (batch in dims and seq in dims and np.prod(dims) >= min_elems):
+            continue
+        if op == "dynamic-update-slice":
+            update = arrays[re.findall(r"%([\w.-]+)", rest)[1]][0]
+            if np.prod([int(d) for d in update[1].split(",") if d]) < min_elems:
+                continue
+        if op in ("copy-start", "copy-done") and tuple(dims) in staged:
+            src = arrays[re.findall(r"%([\w.-]+)", rest)[0]][0]
+            if src[:2] == shapes[0][:2] and src[2].split(":")[0] == shapes[0][2].split(":")[0]:
+                continue
+        found.append(f"{comp}: {name} = {op} {typ}")
+    return found
+
+
+def _mla_three_layers():
+    """deepseek-v2-lite-ep8-ideal's served program (published widths, eight
+    held experts, a 12,800-row vocabulary slice) cut to its dense layer and
+    two MoE layers, so that the MoE stage runs as a loop."""
+    import dataclasses
+
+    from repro.configs.base import StageSpec
+
+    return dataclasses.replace(
+        get_config("deepseek-v2-lite"), n_layers=3, vocab_size=12800,
+        stages=(StageSpec(kinds=("attn",), repeats=1, moe=(False,)),
+                StageSpec(kinds=("attn",), repeats=2, moe=(True,))),
+        moe_experts_held=8, moe_capacity_factor=64 / 6, crossbar_row_ranges=True)
+
+
+@pytest.mark.parametrize("which,batch,max_seq,staged", [
+    pytest.param("smollm-360m", 32, 1024, (), id="smollm-360m-32-1024"),
+    # the dense stage's one-layer rope-key stack (64 MiB) is the one array
+    # of the cache that memory-space assignment stages on chip
+    pytest.param("deepseek-v2-lite-ep8-ideal", 32, 8192, ((1, 32, 8192, 1, 64),),
+                 id="deepseek-v2-lite-ep8-ideal-32-8192"),
+])
+def test_decode_step_writes_the_cache_in_place(one_chip, monkeypatch, which, batch, max_seq,
+                                               staged):
+    """The decode step keeps each stage's cache in one donated buffer: no
+    layer of it is copied, relaid out, sliced out, scattered into a fresh
+    buffer or restacked, and the whole cache aliases the step's output."""
+    if which == "smollm-360m":
+        cfg = with_depth(get_config(which), 2)
+    else:
+        cfg = _mla_three_layers()
+    compiled, cache = _compiled_decode_step(cfg, one_chip, batch, max_seq, monkeypatch)
+    leaves = jax.tree.leaves(cache)
+    layer = min(int(np.prod(a.shape[1:])) for a in leaves)
+    assert layer >= batch * max_seq * 64
+    found = _cache_sized_copies(compiled.as_text(), batch, max_seq, layer, staged)
+    assert not found, "\n".join(found)
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    assert compiled.memory_analysis().alias_size_in_bytes >= cache_bytes
 
 
 @pytest.fixture(scope="module")

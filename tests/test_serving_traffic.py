@@ -411,6 +411,38 @@ def test_page_out_in_round_trip_exact(tiny_lm):
         np.testing.assert_array_equal(w[:, :pos], g[:, :pos])
 
 
+@pytest.mark.parametrize("arch", ["deepseek-v2-236b", "jamba-v0.1-52b"])
+def test_page_round_trip_follows_each_leafs_sequence_axis(arch):
+    """MLA's latents and rope keys (with their unit heads axis) and
+    recurrent states, which have no sequence: a paged slot's prefix comes
+    back whole, and a state whole."""
+    from repro import configs
+    from repro.configs.base import reduced
+
+    cfg = reduced(configs.get_config(arch))
+    kv = BlockKVCache(cfg, max_batch=2, max_seq=24, block=BlockCacheConfig(block_size=4))
+    leaves, tree = jax.tree.flatten(kv.cache)
+    keys = jax.random.split(jax.random.PRNGKey(5), len(leaves))
+    kv.cache = jax.tree.unflatten(
+        tree, [jax.random.normal(k, a.shape, a.dtype) for k, a in zip(keys, leaves)])
+    want = jax.tree.map(lambda l: np.asarray(l[:, 0]), kv.cache)
+    pos = 10
+    kv.allocate(0, pos)
+    kv.page_out(0, 0, pos, 3)
+    kv.cache = jax.tree.map(lambda l: l.at[:, 1].set(-1.0), kv.cache)
+    assert kv.page_in(0, 1) == (pos, 3)
+    axes = M.cache_axes(cfg)
+    flat_axes = jax.tree.leaves(axes, is_leaf=lambda x: isinstance(x, tuple))
+    for ax, w, leaf in zip(flat_axes, jax.tree.leaves(want), jax.tree.leaves(kv.cache)):
+        g = np.asarray(leaf[:, 1])
+        if "cache_seq" not in ax:
+            np.testing.assert_array_equal(w, g)
+            continue
+        seq = ax.index("cache_seq") - 1  # the slot axis is dropped
+        np.testing.assert_array_equal(np.take(w, range(pos), seq), np.take(g, range(pos), seq))
+        assert (np.take(g, range(pos, g.shape[seq]), seq) == -1.0).all()
+
+
 def test_page_out_frees_blocks(tiny_lm):
     cfg, params = tiny_lm
     runner = ModelRunner(cfg, params, max_seq=32, seed=0)
